@@ -117,6 +117,37 @@ def exaq_accumulate_stage(s, m, valid, *, levels: int, clip: float, lut: tuple[f
     return e, dden
 
 
+def load_pool_block(ref, sc_ref, sub_ref, row, *, bs: int, kv_quant: bool, kv_int4: bool,
+                    n_sub: int, sub_bs: int):
+    """One pool block from its VMEM ref to fp32 rows, dequantized — shared by
+    BOTH paged kernels, which must dequantize identically.
+
+    ``row`` is the block's flat (block, kv-head) index into the scale planes,
+    which are scalar-prefetched as 1-D arrays. int8: ``codes * scale``. int4:
+    the ref holds packed nibbles (bs, Pp); split/re-bias them to (bs, 2*Pp)
+    codes and scale each sub_bs-row group by its effective scale
+    ``block_scale * sub_code / 15`` — the same multiply order as
+    ``kv_codec.kv4_effective_scale``, so kernel and gather oracle agree to
+    fp32 roundoff. The per-row scale column is built by a static loop over
+    the n_sub scalar codes (scalar broadcasts, no gather)."""
+    x = ref[0, 0]
+    if kv_int4:
+        xi = x.astype(jnp.int32)  # Mosaic shifts 32-bit lanes, not uint8
+        lo = (xi & 0xF) - INT4_BIAS
+        hi = (xi >> 4) - INT4_BIAS
+        codes = jnp.stack([lo, hi], axis=-1).reshape(bs, 2 * x.shape[-1])
+        parts = []
+        for sg in range(n_sub):
+            s_eff = sc_ref[row] * sub_ref[row * n_sub + sg].astype(jnp.float32) * INV_SUB_LEVELS
+            parts.append(s_eff * jnp.ones((sub_bs, 1), jnp.float32))
+        row_scale = jnp.concatenate(parts, axis=0) if n_sub > 1 else parts[0]
+        return codes.astype(jnp.float32) * row_scale
+    x = x.astype(jnp.float32)
+    if kv_quant:
+        x = x * sc_ref[row]  # dequant in VMEM: HBM moved 1 byte/elt
+    return x
+
+
 def _paged_decode_kernel(
     tables_ref,
     lens_ref,
@@ -128,6 +159,7 @@ def _paged_decode_kernel(
     clip: float,
     lut: tuple[float, ...],
     scale: float,
+    kv: int,
     kv_quant: bool,
     kv_int4: bool = False,
     n_sub: int = 0,
@@ -169,30 +201,8 @@ def _paged_decode_kernel(
     valid = col < kv_len
 
     def _load_kv(ref, sc_ref, sub_ref):
-        """One pool block from its VMEM ref to fp32 rows, dequantized.
-
-        int4: the ref holds packed nibbles (bs, Pp); split/re-bias them to
-        (bs, 2*Pp) codes and scale each sub_bs-row group by its effective
-        scale ``block_scale * sub_code / 15`` — the same multiply order as
-        ``kv_codec.kv4_effective_scale``, so kernel and gather oracle agree
-        to fp32 roundoff. The per-row scale column is built by a static loop
-        over the n_sub scalar codes (scalar broadcasts, no gather)."""
-        x = ref[0, 0]
-        if kv_int4:
-            lo = (x & 0xF).astype(jnp.int32) - INT4_BIAS
-            hi = (x >> 4).astype(jnp.int32) - INT4_BIAS
-            codes = jnp.stack([lo, hi], axis=-1).reshape(bs, 2 * x.shape[-1])
-            parts = []
-            for sg in range(n_sub):
-                s_eff = sc_ref[blk, head] * sub_ref[blk, head, sg].astype(jnp.float32) \
-                    * INV_SUB_LEVELS
-                parts.append(s_eff * jnp.ones((sub_bs, 1), jnp.float32))
-            row_scale = jnp.concatenate(parts, axis=0) if n_sub > 1 else parts[0]
-            return codes.astype(jnp.float32) * row_scale
-        x = x.astype(jnp.float32)
-        if kv_quant:
-            x = x * sc_ref[blk, head]  # dequant in VMEM: HBM moved 1 byte/elt
-        return x
+        return load_pool_block(ref, sc_ref, sub_ref, blk * kv + head, bs=bs, kv_quant=kv_quant,
+                               kv_int4=kv_int4, n_sub=n_sub, sub_bs=sub_bs)
 
     def _scores():
         q = q_ref[0, 0].astype(jnp.float32)
@@ -327,15 +337,18 @@ def exaq_paged_decode_attention(
     def _q_index(s, h, j, tbl, lns, *sc):
         return (s, h, 0, 0)
 
-    # the dequant scales ride the scalar-prefetch channel: (N, KV) fp32 is
-    # SMEM-sized (a few hundred KiB at 7B serving shapes) and the kernel
-    # indexes it by the same prefetched table entry that steered the DMA.
-    # int4 adds the (N, KV, n_sub) sub codes, widened to int32 (SMEM scalars)
+    # the dequant scales ride the scalar-prefetch channel and the kernel
+    # indexes them by the same prefetched table entry that steered the DMA.
+    # They go in flat: SMEM pads a 2-D array's minor dim to 128 lanes, which
+    # would blow (N, KV=4) planes up 32x past SMEM's 1 MiB at a 1024-block
+    # pool. int4 adds the (N, KV, n_sub) sub codes, widened to int32 (SMEM
+    # scalars are 32-bit)
     prefetch = (tables, lens)
     if want_scales:
-        prefetch += (k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
+        prefetch += (k_scale.astype(jnp.float32).reshape(-1),
+                     v_scale.astype(jnp.float32).reshape(-1))
     if kv_int4:
-        prefetch += (k_sub.astype(jnp.int32), v_sub.astype(jnp.int32))
+        prefetch += (k_sub.astype(jnp.int32).reshape(-1), v_sub.astype(jnp.int32).reshape(-1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(S, KV, 2 * MB),
@@ -355,7 +368,7 @@ def exaq_paged_decode_attention(
         _paged_decode_kernel,
         bs=bs, mb=MB, block_q=block_q,
         levels=params.levels, clip=float(params.clip), lut=lut, scale=float(scale),
-        kv_quant=kv_quant, kv_int4=kv_int4, n_sub=n_sub, sub_bs=sub_bs,
+        kv=KV, kv_quant=kv_quant, kv_int4=kv_int4, n_sub=n_sub, sub_bs=sub_bs,
     )
     out = pl.pallas_call(
         kern,
@@ -363,7 +376,7 @@ def exaq_paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((S, KV, block_q, d_pad), jnp.float32),
         # only the chunk axis carries scratch state; (slot, kv_head) programs
         # are independent and may partition across cores
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -84,11 +84,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# constants and the accumulate stage are shared with the decode kernel: the
-# two paged kernels must mask, pad, and quantize identically for the
-# decode-vs-prefill parity contract to hold
-from repro.kernels.exaq_paged_attention import _LANES, _NEG_BIG, _round_up, exaq_accumulate_stage
-from repro.kernels.kv_codec import INT4_BIAS, INV_SUB_LEVELS, kv4_num_sub
+# constants, block dequant and the accumulate stage are shared with the
+# decode kernel: the two paged kernels must mask, pad, dequantize and
+# quantize identically for the decode-vs-prefill parity contract to hold
+from repro.kernels.exaq_paged_attention import (
+    _LANES,
+    _NEG_BIG,
+    _round_up,
+    exaq_accumulate_stage,
+    load_pool_block,
+)
+from repro.kernels.kv_codec import kv4_num_sub
 
 
 def _paged_prefill_kernel(
@@ -104,6 +110,7 @@ def _paged_prefill_kernel(
     clip: float,
     lut: tuple[float, ...],
     scale: float,
+    kv: int,
     kv_quant: bool,
     kv_int4: bool = False,
     n_sub: int = 0,
@@ -147,26 +154,8 @@ def _paged_prefill_kernel(
     valid = (rows < group * chunk) & (col <= start + rows % chunk)
 
     def _load_kv(ref, sc_ref, sub_ref):
-        """One pool block from its VMEM ref to fp32 rows, dequantized —
-        kept arithmetic-identical to the decode kernel's ``_load_kv`` and
-        ``kv_codec.kv4_effective_scale`` (same multiply order) so fused
-        prefill matches the gather oracle to fp32 roundoff."""
-        x = ref[0, 0]
-        if kv_int4:
-            lo = (x & 0xF).astype(jnp.int32) - INT4_BIAS
-            hi = (x >> 4).astype(jnp.int32) - INT4_BIAS
-            codes = jnp.stack([lo, hi], axis=-1).reshape(bs, 2 * x.shape[-1])
-            parts = []
-            for sg in range(n_sub):
-                s_eff = sc_ref[blk, head] * sub_ref[blk, head, sg].astype(jnp.float32) \
-                    * INV_SUB_LEVELS
-                parts.append(s_eff * jnp.ones((sub_bs, 1), jnp.float32))
-            row_scale = jnp.concatenate(parts, axis=0) if n_sub > 1 else parts[0]
-            return codes.astype(jnp.float32) * row_scale
-        x = x.astype(jnp.float32)
-        if kv_quant:
-            x = x * sc_ref[blk, head]  # dequant in VMEM: HBM moved 1 byte/elt
-        return x
+        return load_pool_block(ref, sc_ref, sub_ref, blk * kv + head, bs=bs, kv_quant=kv_quant,
+                               kv_int4=kv_int4, n_sub=n_sub, sub_bs=sub_bs)
 
     def _scores():
         q = q_ref[0].astype(jnp.float32)
@@ -304,11 +293,13 @@ def exaq_paged_prefill_attention(
     def _q_index(h, j, tbl, inf, *sc):
         return (h, 0, 0)
 
+    # scale planes prefetch flat, as in the decode kernel (SMEM lane padding)
     prefetch = (table, info)
     if want_scales:
-        prefetch += (k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
+        prefetch += (k_scale.astype(jnp.float32).reshape(-1),
+                     v_scale.astype(jnp.float32).reshape(-1))
     if kv_int4:
-        prefetch += (k_sub.astype(jnp.int32), v_sub.astype(jnp.int32))
+        prefetch += (k_sub.astype(jnp.int32).reshape(-1), v_sub.astype(jnp.int32).reshape(-1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(KV, 2 * MB),
@@ -328,7 +319,7 @@ def exaq_paged_prefill_attention(
         _paged_prefill_kernel,
         bs=bs, mb=MB, block_q=block_q, chunk=C, group=group,
         levels=params.levels, clip=float(params.clip), lut=lut, scale=float(scale),
-        kv_quant=kv_quant, kv_int4=kv_int4, n_sub=n_sub, sub_bs=sub_bs,
+        kv=KV, kv_quant=kv_quant, kv_int4=kv_int4, n_sub=n_sub, sub_bs=sub_bs,
     )
     out = pl.pallas_call(
         kern,
@@ -336,7 +327,7 @@ def exaq_paged_prefill_attention(
         out_shape=jax.ShapeDtypeStruct((KV, block_q, d_pad), jnp.float32),
         # only the chunk axis carries scratch state; kv-head programs are
         # independent and may partition across cores
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -356,7 +356,6 @@ def _tp_paged_attention(mesh, local_fn, head_args, table_args, scales):
     contraction must run whole on every shard, or fp reassociation in a
     partitioned psum would break greedy parity vs a single-shard engine.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     heads = P(None, "model", None, None)  # q / pool planes are all rank 4
@@ -368,11 +367,11 @@ def _tp_paged_attention(mesh, local_fn, head_args, table_args, scales):
             for a in scales
         )
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=in_specs,
         out_specs=P(None, "model", None, None),
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(*head_args, *table_args, *scales)
     return jax.lax.with_sharding_constraint(out, NamedSharding(mesh, P()))

@@ -13,16 +13,19 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return jax.make_mesh(shape, axes)
+    """The one mesh constructor: every axis Auto. ``jax.make_mesh`` defaults
+    to Explicit axes, under which ``with_sharding_constraint`` and the
+    gather-indexed paths of the sharding rules (runtime/sharding.py) fail."""
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(data: int = 2, model: int = 2):
     """Small mesh for unit tests (requires >= data*model local devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_replica_meshes(dp: int, tp: int, devices=None):

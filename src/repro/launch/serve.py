@@ -36,7 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
-from repro.models import build_model
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models import init_params
 from repro.runtime import serve as serve_rt
 from repro.runtime.engine_core import EngineConfig, Request
 from repro.runtime.sampling import SamplingParams
@@ -247,6 +248,7 @@ def main():
                          "hint instead of growing the queue")
     args = ap.parse_args()
     validate_serve_args(args, device_count=jax.device_count())
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -257,8 +259,7 @@ def main():
         # recurrence must run per token so the checkpoints reproduce the
         # rectangular scan bit-exactly (DESIGN.md §13)
         cfg = dataclasses.replace(cfg, ssm_chunk=1)
-    m = build_model(cfg)
-    params = m.init(jax.random.PRNGKey(0), jnp.bfloat16)
+    params = init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)
     rng = np.random.default_rng(args.seed)
     sp = SamplingParams(temperature=args.temperature, top_k=args.top_k, top_p=args.top_p)
     eos = None if args.eos_id < 0 else args.eos_id

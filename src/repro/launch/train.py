@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro.checkpointing.manager import CheckpointManager
 from repro.configs import get_config
 from repro.data.pipeline import SyntheticLMData
+from repro.launch.mesh import make_mesh
 from repro.optim.adamw import AdamW
 from repro.optim.schedule import cosine_with_warmup
 from repro.runtime import sharding as shd
@@ -61,7 +62,7 @@ def main():
 
     n_dev = len(jax.devices())
     dsize = args.data_mesh or max(n_dev // args.model_mesh, 1)
-    mesh = jax.make_mesh((dsize, args.model_mesh), ("data", "model")) if dsize * args.model_mesh > 1 else None
+    mesh = make_mesh((dsize, args.model_mesh), ("data", "model")) if dsize * args.model_mesh > 1 else None
 
     opt = AdamW(lr=cosine_with_warmup(args.lr, 20, args.steps))
     data = SyntheticLMData(cfg.vocab_size, args.seq, args.batch, seed=0)

@@ -263,13 +263,11 @@ def _fused_prefill_attention(qh, kh, vh, cfg, statics: AttnStatics):
             vl = jax.lax.dynamic_slice_in_dim(v, i * cnt, cnt, axis=1)
         return ops.exaq_attention(q, kl, vl, p, scale, block_q=256, block_kv=512)
 
-    from jax.experimental.shard_map import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, "model", None, None), P(dp, None, None, None), P(dp, None, None, None)),
         out_specs=P(dp, "model", None, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(qh, kh, vh)
 
@@ -669,7 +667,6 @@ def sp_decode_attention(qh, k_new, v_new, cache_k, cache_v, pos, cfg, statics: A
     qh: (B,H,1,Dh); k_new/v_new: (B,KV,1,Dh); cache_{k,v}: (B,KV,Smax,Dh).
     Returns (out (B,H,1,Dh) fp32, new_cache_k, new_cache_v).
     """
-    from jax.experimental.shard_map import shard_map
     from repro.runtime import sharding as shd
 
     mesh = shd._CTX["mesh"]
@@ -724,11 +721,11 @@ def sp_decode_attention(qh, k_new, v_new, cache_k, cache_v, pos, cfg, statics: A
 
     q_spec = P(dp, None, None, None)
     kv_spec = P(dp, None, "model", None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(q_spec, q_spec, q_spec, kv_spec, kv_spec, P(), P()),
         out_specs=(q_spec, kv_spec, kv_spec),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(qh, k_new, v_new, cache_k, cache_v, pos, jnp.asarray(clip, jnp.float32))
 

@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -67,11 +66,11 @@ def pipeline_forward(stage_fn, stage_params, x, mesh, axis: str = "pod", n_micro
 
     other = tuple(a for a in mesh.axis_names if a != axis)
     del other
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x)
 

@@ -14,7 +14,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.models import build_model, default_qstate
+from repro.models import build_model, default_qstate, init_params
 from repro.optim.adamw import AdamW, apply_updates
 from repro.runtime import sharding as shd
 
@@ -54,8 +54,7 @@ def make_loss_fn(cfg, qstate=None, compute_dtype=jnp.bfloat16):
 
 
 def init_train_state(cfg, optimizer: AdamW, key, dtype=jnp.float32) -> dict:
-    model = build_model(cfg)
-    params = model.init(key, dtype)
+    params = init_params(cfg, key, dtype)
     return {"params": params, "opt": optimizer.init(params), "step": jnp.zeros((), jnp.int32)}
 
 

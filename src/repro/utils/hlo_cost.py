@@ -37,18 +37,6 @@ _FREE_OPS = {
 }
 
 
-def xla_cost_analysis(compiled) -> dict:
-    """Compat shim over ``Compiled.cost_analysis()``.
-
-    JAX <= 0.4.x returns a list with one per-device dict; newer releases
-    return the dict directly. Always returns a (possibly empty) dict.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
-
 def _type_bytes(type_str: str) -> float:
     total = 0.0
     for dt, dims in _SHAPE_RE.findall(type_str):

@@ -70,8 +70,7 @@ def test_trip_counted_scan_flops():
     cs = _flops_of(scanned, x, w)
     assert cs.flops == pytest.approx(6 * 2 * 128**3)
     # XLA's own analysis counts the body once — the bug this module fixes
-    # (xla_cost_analysis shims the dict-vs-list-of-dicts return across JAX versions)
-    xla = hlo_cost.xla_cost_analysis(jax.jit(scanned).lower(x, w).compile())["flops"]
+    xla = jax.jit(scanned).lower(x, w).compile().cost_analysis()["flops"]
     # rel=1e-4: XLA adds a handful of scalar flops for the loop counter
     assert xla == pytest.approx(2 * 128**3, rel=1e-4)
 

@@ -24,6 +24,7 @@ def _run(body: str) -> str:
         import jax
         import jax.numpy as jnp
         import numpy as np
+        from repro.launch.mesh import make_mesh
         """
     ) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
@@ -53,7 +54,7 @@ def test_sharded_train_step_matches_single_device():
 
         s1, m1 = jax.jit(step)(state, batch)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         rules = shd.make_activation_rules(cfg, mesh)
         with mesh, shd.activation_rules(mesh, rules):
             st_sh = train_rt.state_shardings(cfg, mesh, jax.eval_shape(lambda: state))
@@ -88,7 +89,7 @@ def test_sharded_exaq_decode_matches_single_device():
         lg1, cache1 = jax.jit(pre)(params, {"tokens": toks}, cache)
         nxt1, cache1, logits1 = jax.jit(dec)(params, jnp.zeros((B, 1), jnp.int32), cache1)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         rules = shd.make_activation_rules(cfg, mesh)
         with mesh, shd.activation_rules(mesh, rules):
             p_sh = shd.tree_shardings(jax.eval_shape(lambda: params), cfg, mesh, mode="serve")
@@ -112,16 +113,15 @@ def test_sharded_exaq_decode_matches_single_device():
 def test_compressed_psum_shard_map():
     print(_run("""
         from functools import partial
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec as P
+        from jax.sharding import PartitionSpec as P
         from repro.optim.compression import compressed_psum
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.normal(0, 1, (8, 256)), jnp.float32)   # one row per device
         err = jnp.zeros_like(g)
 
-        @partial(shard_map, mesh=mesh, in_specs=(P("data", None), P("data", None)),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P("data", None), P("data", None)),
                  out_specs=(P("data", None), P("data", None)))
         def sync(gi, ei):
             m, e2 = compressed_psum(gi[0], ei[0], "data")
@@ -147,7 +147,7 @@ def test_tiny_dryrun_end_to_end():
         from repro.utils import hlo_cost
 
         cfg = get_config("yi-6b").reduced(num_layers=2, d_model=64, d_ff=128, vocab_size=256)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         opt = AdamW(lr=1e-3)
         rules = shd.make_activation_rules(cfg, mesh)
         with mesh, shd.activation_rules(mesh, rules):

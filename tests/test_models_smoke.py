@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, list_configs
-from repro.models import build_model
+from repro.models import build_model, init_params
 from repro.optim.adamw import AdamW
 from repro.runtime.train import init_train_state, make_train_step
 
@@ -41,6 +41,21 @@ def test_forward_shapes_and_finite(arch):
     logits, aux = m.forward_train(params, batch)
     assert logits.shape == (2, 32, cfg.vocab_size)
     assert bool(jnp.isfinite(logits).all())
+
+
+@pytest.mark.parametrize("arch", ["yi-6b", "deepseek-moe-16b", "zamba2-2.7b"])
+def test_jitted_init_matches_eager(arch):
+    """``init_params`` (one jitted program, the only way a full-width tree fits
+    a chip) draws the same keys in the same order as the eager ``init``: same
+    tree, shapes and dtypes, and the same values."""
+    cfg = get_config(arch).reduced()
+    key = jax.random.PRNGKey(0)
+    eager = build_model(cfg).init(key, jnp.bfloat16)
+    jitted = init_params(cfg, key, jnp.bfloat16)
+    assert jax.tree.structure(eager) == jax.tree.structure(jitted)
+    for a, b in zip(jax.tree.leaves(eager), jax.tree.leaves(jitted)):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype)
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
 
 
 @pytest.mark.parametrize("arch", ALL_ARCHS)

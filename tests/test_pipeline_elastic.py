@@ -17,6 +17,7 @@ def _run(body: str) -> str:
         import jax
         import jax.numpy as jnp
         import numpy as np
+        from repro.launch.mesh import make_mesh
         """
     ) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
@@ -29,7 +30,7 @@ def _run(body: str) -> str:
 def test_gpipe_pipeline_matches_sequential():
     print(_run("""
         from repro.runtime.pipeline_parallel import pipeline_forward, stack_stages
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = make_mesh((4,), ("pod",))
         rng = np.random.default_rng(0)
         L, D = 8, 16
         W = jnp.asarray(rng.normal(0, 0.3, (L, D, D)), jnp.float32)
@@ -74,7 +75,7 @@ def test_elastic_restore_on_different_mesh(tmp_path):
         step = train_rt.make_train_step(cfg, opt, compute_dtype=jnp.float32)
 
         def run_on(mesh_shape, state, batches):
-            mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+            mesh = make_mesh(mesh_shape, ("data", "model"))
             rules = shd.make_activation_rules(cfg, mesh)
             losses = []
             with mesh, shd.activation_rules(mesh, rules):

@@ -64,3 +64,26 @@ def test_device_count_check():
 
 def test_no_fused_flag_is_paged_only_but_impl_agnostic():
     validate_serve_args(_args(paged=True, fused=False, impl="exact"))
+
+
+def test_compile_cache_follows_env_else_checkout(monkeypatch):
+    """Importing the library sets no compile cache; the entry points' helper
+    defers to JAX_COMPILATION_CACHE_DIR when set and otherwise points JAX at
+    the fixed ``.jax_cache/`` of the checkout."""
+    import jax
+
+    import repro.launch.serve  # noqa: F401
+    from repro.launch.compile_cache import CHECKOUT, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    assert before == os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax-cache")
+    assert enable_compile_cache() == "/elsewhere/jax-cache"
+    assert jax.config.jax_compilation_cache_dir == before  # JAX reads the variable itself
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert enable_compile_cache() == str(CHECKOUT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(CHECKOUT / ".jax_cache")
+        assert (CHECKOUT / "chip_smoke.py").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -25,6 +25,7 @@ from jax.sharding import PartitionSpec as P
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs import get_config  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.runtime import sharding as shd  # noqa: E402
 
@@ -148,7 +149,7 @@ def test_hybrid_cache_shardings_pad_stacked_ranks():
     from repro.runtime import serve as serve_rt
 
     cfg = get_config("zamba2-2.7b").reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cache = jax.eval_shape(lambda: build_model(cfg).init_cache(2, 32, jnp.bfloat16))
     shardings = serve_rt.cache_shardings(cfg, mesh, cache)
     for name in ("conv", "ssm", "k", "v"):

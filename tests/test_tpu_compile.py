@@ -1,0 +1,97 @@
+"""Compile-only checks of the main-path Pallas kernels for a described TPU v5e.
+
+The kernel suites (tests/test_paged_attention.py, tests/test_paged_prefill.py)
+run in interpret mode, which accepts any tiling and any amount of SMEM/VMEM.
+Here the TPU compiler that ships with jaxlib compiles the fused paged decode
+and paged prefill kernels for a v5e that is described, not attached, at
+yi-6b serving widths (32 q-heads, 4 kv-heads, head_dim 128, 16-token blocks,
+a 1024-block pool) for every pool dtype, plus zamba2's head_dim 80. Nothing
+runs: a pass means the chip's compiler accepts the kernel, and the compiled
+text holds the Mosaic kernel (``tpu_custom_call``).
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may hold the TPU library, and pytest-xdist workers each
+import every test file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.quantizer import exaq_params
+from repro.kernels.exaq_paged_attention import exaq_paged_decode_attention
+from repro.kernels.exaq_paged_prefill import exaq_paged_prefill_attention
+from repro.kernels.kv_codec import kv4_num_sub
+
+H, KV, BS, N_BLOCKS = 32, 4, 16, 1024
+SLOTS, MAX_BLOCKS = 4, 128  # 2048-token windows
+CHUNK = 32  # EngineConfig's default prefill chunk
+
+CASES = [("bf16", 128), ("int8", 128), ("int4", 128), ("bf16", 80)]
+CASE_IDS = [f"{kv}-d{dh}" for kv, dh in CASES]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an entry written for a described chip cannot be read back without one
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _pool_args(kv_dtype: str, dh: int, sharding) -> tuple[tuple, dict]:
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    if kv_dtype == "int4":
+        pool = sds((N_BLOCKS, KV, BS, dh // 2), jnp.uint8)
+    else:
+        pool = sds((N_BLOCKS, KV, BS, dh), {"bf16": jnp.bfloat16, "int8": jnp.int8}[kv_dtype])
+    planes = {}
+    if kv_dtype in ("int8", "int4"):
+        planes["k_scale"] = planes["v_scale"] = sds((N_BLOCKS, KV), jnp.float32)
+    if kv_dtype == "int4":
+        planes["k_sub"] = planes["v_sub"] = sds((N_BLOCKS, KV, kv4_num_sub(BS)), jnp.uint8)
+    return (pool, pool), planes
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kv_dtype,dh", CASES, ids=CASE_IDS)
+def test_paged_decode_compiles_for_v5e(one_chip, kv_dtype, dh):
+    (pk, pv), planes = _pool_args(kv_dtype, dh, one_chip)
+    q = jax.ShapeDtypeStruct((SLOTS, H, 1, dh), jnp.bfloat16, sharding=one_chip)
+    tables = jax.ShapeDtypeStruct((SLOTS, MAX_BLOCKS), jnp.int32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
+    compiled = exaq_paged_decode_attention.lower(
+        q, pk, pv, tables, lens, exaq_params(2.0, 2), dh**-0.5, **planes
+    ).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("kv_dtype,dh", CASES, ids=CASE_IDS)
+def test_paged_prefill_compiles_for_v5e(one_chip, kv_dtype, dh):
+    (pk, pv), planes = _pool_args(kv_dtype, dh, one_chip)
+    q = jax.ShapeDtypeStruct((1, H, CHUNK, dh), jnp.bfloat16, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((MAX_BLOCKS,), jnp.int32, sharding=one_chip)
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = exaq_paged_prefill_attention.lower(
+        q, pk, pv, table, start, exaq_params(2.0, 2), dh**-0.5, **planes
+    ).compile()
+    _assert_kernel(compiled)

@@ -103,7 +103,9 @@ ARGPARSE_FLAG_RE = re.compile(r"""add_argument\(\s*["'](--[A-Za-z][\w-]*)["']"""
 # a flag token in prose/code blocks: "--" + letter start, not the "---" rule
 README_FLAG_RE = re.compile(r"(?<![\w-])--[A-Za-z][\w-]*")
 # CLI-bearing sources whose flags README may legitimately mention
-FLAG_SOURCE_GLOBS = ["src/repro/launch/*.py", "benchmarks/*.py", "experiments/*.py", "tools/*.py"]
+FLAG_SOURCE_GLOBS = [
+    "chip_smoke.py", "src/repro/launch/*.py", "benchmarks/*.py", "experiments/*.py", "tools/*.py",
+]
 ALWAYS_KNOWN_FLAGS = {
     "--help",  # argparse built-in
     # XLA runtime flag (an XLA_FLAGS env-var value, not a CLI flag): README's
