@@ -28,6 +28,7 @@ dp/tp layout. Other families fall back to the rectangular greedy loop in
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import time
 
@@ -180,6 +181,12 @@ def _serve_online(eng, prompts, args, sp):
               f"backoff_hint={r.backoff_hint:.2f}")
 
 
+def _profiled(log_dir: str | None):
+    """``jax.profiler.trace(log_dir)`` around the serving loop, or nothing
+    when no directory is given."""
+    return jax.profiler.trace(log_dir) if log_dir else contextlib.nullcontext()
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b")
@@ -246,6 +253,10 @@ def main():
                     help="online: admission cap on requests in the system "
                          "(0 = off); overflow is shed at submit with a backoff "
                          "hint instead of growing the queue")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="record a profiler trace of the serving loop into DIR: "
+                         "the engine's engine.* host spans beside its named "
+                         "device programs (jit_paged_decode_chunk, ...)")
     args = ap.parse_args()
     validate_serve_args(args, device_count=jax.device_count())
     enable_compile_cache()
@@ -297,11 +308,13 @@ def main():
         else:
             eng = Engine(cfg, params, config)
         if args.online:
-            _serve_online(eng, prompts, args, sp)
+            with _profiled(args.profile_dir):
+                _serve_online(eng, prompts, args, sp)
             return
         t0 = time.time()
-        uids = [eng.submit(Request(p, args.gen, sp)) for p in prompts]
-        results = eng.run()
+        with _profiled(args.profile_dir):
+            uids = [eng.submit(Request(p, args.gen, sp)) for p in prompts]
+            results = eng.run()
         wall = time.time() - t0
         n_out = sum(len(g.tokens) for g in results.values())
         kind = "paged engine" if args.paged else "engine"
@@ -318,6 +331,8 @@ def main():
             print(f"prefix-cache hit rate {100*eng.prefix_hit_rate:.1f}% "
                   f"({eng.stats['prefix_hit_tokens']}/{eng.stats['prompt_tokens']} prompt tokens); "
                   f"{eng.stats['prefill_chunks']} prefill chunks of {args.prefill_chunk}; "
+                  f"queue wait {1e3*eng.stats['queue_wait_s']/max(eng.stats['admitted'], 1):.1f} "
+                  f"ms per admission; "
                   f"pool {eng.kv_pool_bytes/2**20:.1f} MiB, "
                   f"{st.cow_copies} CoW copies, {st.evictions} evictions")
             if args.spec_k:
@@ -343,8 +358,9 @@ def main():
         B, S = args.slots, args.prompt_len
         prompts = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, S)), jnp.int32)
         t0 = time.time()
-        gen = serve_rt.generate(params, cfg, prompts, args.gen)
-        jax.block_until_ready(gen)
+        with _profiled(args.profile_dir):
+            gen = serve_rt.generate(params, cfg, prompts, args.gen)
+            jax.block_until_ready(gen)
         wall = time.time() - t0
         print(f"rectangular loop ({cfg.family}): {B}x{args.gen} tokens in "
               f"{wall*1e3:.1f} ms ({B*args.gen/max(wall,1e-9):.0f} tok/s incl. compile)")

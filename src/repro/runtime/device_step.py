@@ -6,7 +6,10 @@ ints; this module owns everything that touches a jax array: cache/pool
 construction (placed onto a mesh with the specs from ``runtime/sharding.py``),
 the jitted prefill/decode/scatter functions, the CoW block copy, the int8
 scale resets, and sampling. The engines in ``runtime/engine.py`` glue the two
-layers together.
+layers together. Every jitted program compiles under a stable name that says
+what it does (``jit_paged_decode_chunk``, ``jit_paged_prefill_chunk``,
+``jit_copy_block``, ...; see ``_jit``), which is how a profiler trace lists
+each execution.
 
 Sharding contract (the reason this layer exists):
 
@@ -32,6 +35,8 @@ Sharding contract (the reason this layer exists):
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -41,6 +46,19 @@ from repro.kernels.kv_codec import kv_cache_is_int4
 from repro.models import build_model, default_qstate
 from repro.runtime import sampling as smp
 from repro.runtime import sharding as shd
+
+
+def _jit(fn, name: str, **jit_kw):
+    """``jax.jit(fn, **jit_kw)`` under a stable program name: the compiled
+    module is ``jit_<name>``, which is what a profiler trace lists each
+    executed program by, whatever the Python function is called."""
+
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **jit_kw)
 
 
 def decode_scan(step_kv, kv, tokens, lens, active, budget, temperature, top_k,
@@ -102,7 +120,7 @@ class _DeviceStep:
             # replicated on purpose — see the module docstring's contract
             params = jax.device_put(params, NamedSharding(mesh, P()))
         self.params = params
-        self._jit_sample = jax.jit(smp.sample_tokens)
+        self._jit_sample = _jit(smp.sample_tokens, "sample_first")
 
     def _put(self, x, dtype=None):
         """Host array -> device, replicated on this step's mesh (if any)."""
@@ -137,10 +155,10 @@ class SlotDeviceStep(_DeviceStep):
         # the outputs immediately, so XLA may update the cache in place instead
         # of copying the full (L, slots, KV, max_seq, Dh) arrays per chunk /
         # admission (CPU ignores donation; TPU/GPU halve peak cache memory)
-        self._jit_prefill = jax.jit(self._prefill_fn)
-        self._jit_insert = jax.jit(self._insert_fn, donate_argnums=(0, 1))
-        self._jit_chunk = jax.jit(self._chunk_fn, static_argnames=("steps", "sampler"),
-                                  donate_argnums=(1,))
+        self._jit_prefill = _jit(self._prefill_fn, "slot_prefill")
+        self._jit_insert = _jit(self._insert_fn, "slot_insert", donate_argnums=(0, 1))
+        self._jit_chunk = _jit(self._chunk_fn, "slot_decode_chunk",
+                               static_argnames=("steps", "sampler"), donate_argnums=(1,))
 
     def init_cache(self):
         """Build the slot cache, sharded per ``slot_cache_spec`` on a mesh."""
@@ -217,15 +235,17 @@ class PagedDeviceStep(_DeviceStep):
         self.block_size = block_size
         self.int4 = kv_cache_is_int4(cache_dtype)
         self.quantized = self.int4 or jnp.dtype(cache_dtype) == jnp.int8
-        self._jit_prefill_chunk = jax.jit(self._prefill_chunk_fn, donate_argnums=(1,))
-        self._jit_verify_chunk = jax.jit(self._verify_chunk_fn, donate_argnums=(1,))
-        self._jit_trim_sub = jax.jit(self._trim_sub_fn, donate_argnums=(0,))
+        self._jit_prefill_chunk = _jit(self._prefill_chunk_fn, "paged_prefill_chunk",
+                                       donate_argnums=(1,))
+        self._jit_verify_chunk = _jit(self._verify_chunk_fn, "paged_verify_chunk",
+                                      donate_argnums=(1,))
+        self._jit_trim_sub = _jit(self._trim_sub_fn, "trim_sub_scales", donate_argnums=(0,))
         # raw jitted (pool, src, dst) -> pool; the engine exposes this as
         # ``_jit_copy_block`` (tests drive it directly on a loose pool dict)
-        self.copy_block = jax.jit(self._copy_block_fn, donate_argnums=(0,))
-        self.reset_scales = jax.jit(self._reset_scales_fn, donate_argnums=(0,))
-        self._jit_chunk = jax.jit(self._chunk_fn, static_argnames=("steps", "sampler"),
-                                  donate_argnums=(1,))
+        self.copy_block = _jit(self._copy_block_fn, "copy_block", donate_argnums=(0,))
+        self.reset_scales = _jit(self._reset_scales_fn, "reset_scales", donate_argnums=(0,))
+        self._jit_chunk = _jit(self._chunk_fn, "paged_decode_chunk",
+                               static_argnames=("steps", "sampler"), donate_argnums=(1,))
 
     def init_pool(self) -> dict:
         """Build the block pool, sharded over the mesh: payloads per

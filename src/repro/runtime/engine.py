@@ -76,6 +76,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro.kernels.kv_codec import kv_cache_is_quantized
 from repro.runtime import sampling as smp
@@ -288,13 +289,17 @@ class Engine(HostCore):
                sampling: smp.SamplingParams = smp.GREEDY, *,
                priority: int = 0, deadline: float | None = None) -> int:
         """Submit a ``Request`` (canonical) or the legacy kwarg spread."""
-        return super().submit(prompt, max_new, sampling, priority=priority, deadline=deadline)
+        with span("engine.submit"):
+            return super().submit(prompt, max_new, sampling, priority=priority,
+                                  deadline=deadline)
 
     def _sample_first(self, slot: int, req: Request, logits) -> None:
         """Sample the first generated token from prefill logits (device) and
-        hand the host transition to the core."""
-        self._key, sub = jax.random.split(self._key)
-        first = self._dev.sample_first(logits, req.sampling, sub)
+        hand the host transition to the core. The host blocks here until the
+        device has produced the token."""
+        with span("engine.sample_first", uid=req.uid):
+            self._key, sub = jax.random.split(self._key)
+            first = self._dev.sample_first(logits, req.sampling, sub)
         self._complete_first(slot, req, first)
 
     # ------------------------------------------------------------- scheduling
@@ -305,38 +310,54 @@ class Engine(HostCore):
         free = self._free_slots()
         while free and self._queue:
             req = self._queue.popleft()
+            self._note_admitted(req.uid)
             slot = free.pop(0)
-            P = min(_bucket(len(req.prompt)), self.max_seq)
-            padded = np.zeros((1, P), np.int32)
-            padded[0, : len(req.prompt)] = req.prompt
-            logits, ks, vs = self._dev.prefill(padded, [len(req.prompt)])
-            self._cache_k, self._cache_v = self._dev.insert(
-                self._cache_k, self._cache_v, ks, vs, slot
-            )
-            self.stats["prefills"] += 1
-            self._sample_first(slot, req, logits)
+            with span("engine.prefill", uid=req.uid):
+                P = min(_bucket(len(req.prompt)), self.max_seq)
+                padded = np.zeros((1, P), np.int32)
+                padded[0, : len(req.prompt)] = req.prompt
+                logits, ks, vs = self._dev.prefill(padded, [len(req.prompt)])
+                self._cache_k, self._cache_v = self._dev.insert(
+                    self._cache_k, self._cache_v, ks, vs, slot
+                )
+                self.stats["prefills"] += 1
+                self._sample_first(slot, req, logits)
             admitted += 1
         return admitted
 
     def step_chunk(self, steps: int | None = None) -> int:
-        """Admit + run one jitted decode chunk; returns #tokens emitted."""
-        self._admit()
+        """Admit + run one jitted decode chunk; returns #tokens emitted.
+
+        Every phase runs under an ``engine.*`` profiler span (nested inside
+        ``engine.step``), so a trace can put each device idle gap down to
+        what the host was doing; with no profiler running a span costs one
+        object."""
+        with span("engine.step"):
+            return self._step(steps)
+
+    def _step(self, steps: int | None) -> int:
+        with span("engine.admit"):
+            self._admit()
         if self.num_active == 0:
             return 0
         steps = self._clamp_steps(steps)
         t0 = time.perf_counter()
-        out = self._dev.decode_chunk(
-            {"k": self._cache_k, "v": self._cache_v},
-            self._tokens, self.kv_lens, self._active, self._budget,
-            self._temperature, self._top_k, self._top_p, self._key,
-            steps=steps, sampler=self._pick_sampler(),
-        )
+        with span("engine.decode"):
+            out = self._dev.decode_chunk(
+                {"k": self._cache_k, "v": self._cache_v},
+                self._tokens, self.kv_lens, self._active, self._budget,
+                self._temperature, self._top_k, self._top_p, self._key,
+                steps=steps, sampler=self._pick_sampler(),
+            )
         kv, tokens, lens, active, budget, self._key, emitted, masks = out
-        jax.block_until_ready(emitted)
+        with span("engine.device_wait"):
+            jax.block_until_ready(emitted)
         self.stats["decode_time"] += time.perf_counter() - t0
         self._cache_k, self._cache_v = kv["k"], kv["v"]
         was_active = self._active
-        return self._absorb_chunk(tokens, lens, active, budget, emitted, masks, was_active)
+        with span("engine.absorb"):
+            return self._absorb_chunk(tokens, lens, active, budget, emitted, masks,
+                                      was_active)
 
 
 # ===================================================================== paged
@@ -496,7 +517,8 @@ class PagedEngine(EngineCore, Engine):
     def _drain_copies(self) -> None:
         copies = self.take_pending_copies()
         if copies:
-            self._pool = self._dev.copy_blocks(self._pool, copies)
+            with span("engine.copy_blocks"):
+                self._pool = self._dev.copy_blocks(self._pool, copies)
 
     def _flush_fresh_scales(self) -> None:
         """Reset the scale planes of blocks allocated since the last launch.
@@ -511,7 +533,8 @@ class PagedEngine(EngineCore, Engine):
         n = _bucket(len(fresh), 8)
         ids = np.full((n,), NULL_BLOCK, np.int32)
         ids[: len(fresh)] = fresh
-        self._pool = self._dev.reset_fresh_scales(self._pool, ids)
+        with span("engine.reset_scales"):
+            self._pool = self._dev.reset_fresh_scales(self._pool, ids)
 
     # ------------------------------------------------------------- scheduling
 
@@ -533,34 +556,41 @@ class PagedEngine(EngineCore, Engine):
             if self._active[slot] and int(self.kv_lens[slot]) >= self.max_seq:
                 self._finish(slot, "length")
 
-    def step_chunk(self, steps: int | None = None) -> int:
+    def _step(self, steps: int | None) -> int:
         """Admit; advance one prefill chunk per prefilling slot; run one
         jitted decode chunk over the active slots. Returns #tokens emitted."""
-        self._admit()
+        with span("engine.admit"):
+            self._admit()
         for i, s in enumerate(self._slots):
             if not s.free and s.prefilling:
-                self._prefill_step(i)
+                with span("engine.prefill", uid=s.uid):
+                    self._prefill_step(i)
         if self.num_active == 0:
             return 0
         if self.spec_k > 0:
             return self._spec_chunk()
         steps = self._clamp_steps(steps)
-        self._reserve_chunk_blocks(steps)  # may preempt slots under pool pressure
+        with span("engine.reserve"):
+            self._reserve_chunk_blocks(steps)  # may preempt slots under pool pressure
         if self.num_active == 0:
             return 0
         self._flush_fresh_scales()
         t0 = time.perf_counter()
-        out = self._dev.decode_chunk(
-            self._pool, self._tables, self._tokens, self.kv_lens, self._active,
-            self._budget, self._temperature, self._top_k, self._top_p, self._key,
-            steps=steps, sampler=self._pick_sampler(),
-        )
+        with span("engine.decode"):
+            out = self._dev.decode_chunk(
+                self._pool, self._tables, self._tokens, self.kv_lens, self._active,
+                self._budget, self._temperature, self._top_k, self._top_p, self._key,
+                steps=steps, sampler=self._pick_sampler(),
+            )
         pool, tokens, lens, active, budget, self._key, emitted, masks = out
-        jax.block_until_ready(emitted)
+        with span("engine.device_wait"):
+            jax.block_until_ready(emitted)
         self.stats["decode_time"] += time.perf_counter() - t0
         self._pool = pool
         was_active = self._active
-        return self._absorb_chunk(tokens, lens, active, budget, emitted, masks, was_active)
+        with span("engine.absorb"):
+            return self._absorb_chunk(tokens, lens, active, budget, emitted, masks,
+                                      was_active)
 
     # ------------------------------------------------- speculative decoding
 
@@ -572,7 +602,8 @@ class PagedEngine(EngineCore, Engine):
         n_out = 0
         for slot in range(self.max_slots):
             if self._active[slot]:
-                n_out += self._spec_round(slot)
+                with span("engine.spec_round", uid=self._slots[slot].uid):
+                    n_out += self._spec_round(slot)
         return n_out
 
     def _spec_round(self, slot: int) -> int:

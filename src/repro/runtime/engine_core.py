@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import time
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -341,11 +342,14 @@ class HostCore:
         self._preempt_carry: dict[int, list[int]] = {}
         self._submit_time: dict[int, float] = {}
         self.ttft: dict[int, float] = {}  # uid -> first-token latency in now() units
+        # uid -> perf_counter() when the request (or its preempted
+        # continuation) entered the queue; admission turns it into queue_wait_s
+        self._queued_at: dict[int, float] = {}
 
         # telemetry for bench_serving
         self.stats = {"decode_steps": 0, "tokens_out": 0, "occupancy_sum": 0.0,
                       "max_active": 0, "prefills": 0, "decode_time": 0.0,
-                      "cancelled": 0, "shed": 0}
+                      "cancelled": 0, "shed": 0, "admitted": 0, "queue_wait_s": 0.0}
 
     def _new_slot(self):
         return _Slot()
@@ -409,7 +413,15 @@ class HostCore:
         req = dataclasses.replace(req, uid=uid)
         self._queue.append(req)
         self._submit_time[uid] = self.now()
+        self._queued_at[uid] = time.perf_counter()
         return uid
+
+    def _note_admitted(self, uid: int) -> None:
+        """A queued request moved into a slot: count it and the host-clock
+        seconds it waited in the queue since it last entered it."""
+        now = time.perf_counter()
+        self.stats["queue_wait_s"] += now - self._queued_at.pop(uid, now)
+        self.stats["admitted"] += 1
 
     def submit(self, prompt, max_new: int | None = None, sampling=GREEDY, *,
                priority: int = 0, deadline: float | None = None) -> int:
@@ -472,6 +484,7 @@ class HostCore:
             carry = self._preempt_carry.pop(uid, [])
             self._results[uid] = Generation(uid, carry, "cancelled")
             self._submit_time.pop(uid, None)
+            self._queued_at.pop(uid, None)
             self.stats["cancelled"] += 1
             return True
         for slot, s in enumerate(self._slots):
@@ -528,6 +541,7 @@ class HostCore:
                 occupancy=self._occupancy(), uid=uid,
             )
             self._submit_time.pop(uid, None)
+            self._queued_at.pop(uid, None)
             self.stats["shed"] += 1
         return len(expired)
 
@@ -690,7 +704,7 @@ class EngineCore(HostCore):
         # with the slot.
         self._branches: dict[int, list[SpecBranch]] = {}
         self.stats.update(spec_rounds=0, spec_drafted=0, spec_accepted=0,
-                          spec_emitted=0, spec_forks=0)
+                          spec_emitted=0)
 
     def _new_slot(self):
         return _PagedSlot()
@@ -829,6 +843,7 @@ class EngineCore(HostCore):
         self._slots[slot] = self._new_slot()
         self._active[slot] = False
         self.stats["preemptions"] += 1
+        self._queued_at[req.uid] = time.perf_counter()
         self._queue.appendleft(cont)  # continuation bypasses _validate_request:
         # its prompt may legitimately reach max_seq (finishes right after prefill)
 
@@ -943,6 +958,7 @@ class EngineCore(HostCore):
                 free = self._free_slots()
                 continue
             self._queue.popleft()
+            self._note_admitted(req.uid)
             slot = free.pop(0)
             s = self._slots[slot]
             s.uid, s.req, s.table, s.hashes = req.uid, req, table, hashes
@@ -1047,8 +1063,6 @@ class EngineCore(HostCore):
             raise
         br = SpecBranch(slot, s.uid, drafts, L, bi0, table, forked)
         self._branches.setdefault(slot, []).append(br)
-        if forked:
-            self.stats["spec_forks"] += 1
         # window rows: the pending token (sampled last round, KV not yet
         # written) then the k drafts — C = k + 1 rows at positions [L, L + k]
         C = k + 1
