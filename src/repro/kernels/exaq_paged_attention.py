@@ -7,27 +7,31 @@ once to build the copy, the copy is written, then read again by attention.
 Three rectangular passes of bandwidth to feed a softmax whose whole point
 (paper §4, Table 3) is to be cheaper than a memcpy.
 
-Here the block table drives the DMA directly: the grid is
-``(slot, kv_head, chunk)`` and the K/V BlockSpec index maps read the
-*scalar-prefetched* block table, so each grid step pulls one pool block
-``tables[slot, chunk]`` from HBM into VMEM at its natural layout — no
-intermediate copy exists. Dead-tail chunks (``chunk * bs >= kv_lens[slot]``)
-are remapped to the null block (id 0); consecutive identical indices collapse
-to a single DMA, so bytes moved track *live* tokens, not table width.
+Here the block table drives the DMA directly. The kernel runs one program
+per slot; the pools stay in HBM (``memory_space=pl.ANY``) and the block
+table and ``kv_lens`` are *scalar-prefetched*. The program walks only the
+slot's live KV: ``cdiv(kv_lens[slot], T)`` tiles of ``T`` tokens, each
+tile's live pool blocks copied by async DMA from the table straight into a
+VMEM double buffer while the previous tile is computed — no intermediate
+copy exists, and no step of the kernel touches a dead table entry. A copy
+moves one whole pool block, all kv heads ``(KV, bs, Dh)`` at once, and the
+program scores all heads' query groups in one batched dot. ``T`` follows
+from the shapes (``decode_tile_tokens``): the most tokens up to 512 whose K
+and V double buffers fit 4 MiB of VMEM, capped at the table, at least one
+block.
 
-Chunk-combine semantics are the global grid of ``exaq_softmax_chunked``
-(exact Algo. 2, DESIGN.md §2): the chunk axis runs two passes over the
-table — pass 1 reduces the global row max across live chunks, pass 2
-re-reads K, quantizes every chunk's scores on the grid anchored at that max,
-and accumulates the PV numerator plus the 2^M-bin histogram denominator.
-Counts on a shared grid add exactly across chunks, so block boundaries are
-invisible to the softmax and the kernel is bit-comparable to the
-gather-then-dispatch reference (``kernels.ops.paged_decode_attention`` with
-``use_kernel=False``) instead of only statistically close like the online
-running-max kernels. V is fetched in pass 2 only (its pass-1 index map pins
-the null block), so the fused path moves ~2x K + 1x V of *live* window bytes
-versus the gather path's live pool read plus two rectangular passes over the
-dense copy (see ``paged_decode_bytes_model``).
+Combine semantics are the global grid of ``exaq_softmax_chunked`` (exact
+Algo. 2, DESIGN.md §2): two loops over the live tiles — the first reduces
+the global row max, the second re-reads K, quantizes every tile's scores on
+the grid anchored at that max, and accumulates the PV numerator plus the
+2^M-bin histogram denominator. Counts on a shared grid add exactly across
+tiles, so tile and block boundaries are invisible to the softmax and the
+kernel is bit-comparable to the gather-then-dispatch reference
+(``kernels.ops.paged_decode_attention`` with ``use_kernel=False``) instead
+of only statistically close like the online running-max kernels. V is
+copied in the second loop only, so the fused path moves ~2x K + 1x V of
+*live* window bytes versus the gather path's live pool read plus two
+rectangular passes over the dense copy (see ``paged_decode_bytes_model``).
 
 GQA is native: q is laid out ``(slots, KV, group, Dh)`` so one kv head's
 query group forms the q-block rows — K/V are never repeated ``group`` times
@@ -35,8 +39,8 @@ in memory (the repeat the unfused path pays via ``repeat_kv``).
 
 Int8 pools (DESIGN.md §6): when the pool stores int8 codes with
 per-(block, kv-head) scales, the scales ride the *scalar-prefetch* channel
-next to the block tables, and each K/V block is dequantized in VMEM right
-after its 8-bit DMA lands — ``codes.astype(f32) * scale[blk, kv_head]`` —
+next to the block tables, and each K/V tile is dequantized in VMEM right
+after its 8-bit DMAs land — ``codes.astype(f32) * scale[blk, kv_head]`` —
 before the EXAQ clip/LUT stages. HBM only ever moves the 1-byte payload,
 so the modeled bytes/step drop ~2x vs bf16 (~4x vs fp32); the EXAQ
 histogram math downstream is unchanged, and the kernel stays bit-comparable
@@ -48,10 +52,14 @@ the per-(block, kv-head, sub-block) uint8 scale codes join the block scales
 on the scalar-prefetch channel. The DMA lands the *packed* block in VMEM;
 nibbles are split, re-biased, and scaled by
 ``block_scale * sub_code / 15`` per sub-block row group right there —
-no dense dequantized (or even unpacked) copy ever exists in HBM. q/out/acc
-stay at the unpacked width ``2 * lane_pad(Dh/2)``; q's zero lane-padding
-nulls the K-side garbage that padded nibbles decode to, and the V-side
-garbage lands in output lanes >= Dh that the final slice drops.
+no dense dequantized (or even unpacked) copy ever exists in HBM. The split
+is not re-interleaved: low nibbles (even head dims) fill lanes
+``[0, lane_pad(Dh/2))`` and high nibbles (odd dims) the next as many, and
+the wrapper lays q out the same way and restores the output's dim order,
+so the kernel never shuffles lanes. q/out/acc stay at the unpacked width
+``2 * lane_pad(Dh/2)``; q's zero lane-padding nulls the K-side garbage
+that padded nibbles decode to, and the V-side garbage lands in output
+lanes the final slice drops.
 
 Layouts: q ``(S, H, 1, Dh)``; pool_k/pool_v ``(N, KV, bs, Dh)`` (int4:
 ``(N, KV, bs, Dh/2)`` uint8); block_tables ``(S, MB)`` int32; kv_lens
@@ -64,11 +72,12 @@ Tensor-parallel contract (DESIGN.md §9): under a mesh whose 'model' axis
 divides KV, ``kernels.ops.paged_decode_attention`` wraps this kernel in a
 shard_map that splits q's H axis and the pool's KV axis by the same factor
 and replicates tables/lens/scalars. The kernel itself is unchanged — inside
-the shard_map its ``pool_k``/``pool_v`` are the *local* head partition and
-its grid's kv_head axis runs over local heads only, so index maps never see
-a global head id (q head ``h``'s group maps to local kv head ``h // group``
-exactly as on one device). Per-(slot, head) rows are computed whole on one
-shard, making the sharded kernel bit-exact vs the single-shard dispatch.
+the shard_map its ``pool_k``/``pool_v`` are the *local* head partition,
+each copy moves a block's local heads and the program computes local
+heads only, so the kernel never sees a global head id (q head ``h``'s
+group maps to local kv head ``h // group`` exactly as on one device).
+Per-(slot, head) rows are computed whole on one shard, making the sharded
+kernel bit-exact vs the single-shard dispatch.
 """
 
 from __future__ import annotations
@@ -80,7 +89,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.kv_codec import INT4_BIAS, INV_SUB_LEVELS, kv4_num_sub
+from repro.kernels.kv_codec import INT4_BIAS, INV_SUB_LEVELS, kv4_num_sub, kv_cache_is_int4
 
 _NEG_BIG = -1e30
 _LANES = 128
@@ -95,12 +104,13 @@ def exaq_accumulate_stage(s, m, valid, *, levels: int, clip: float, lut: tuple[f
     kernels' accumulate passes (decode and prefill — they must stay
     bit-identical for the decode-vs-prefill parity contract to hold).
 
-    s: (rows, bs) raw scores (masked lanes already at -inf); m: (rows, 1)
-    the global row max from pass 1 — the shared quantization anchor; valid:
-    (rows, bs) live-lane mask. Returns (e, dden): the LUT-reconstructed
-    unnormalized weights (masked lanes zeroed) and this chunk's partial
-    histogram denominator — integer counts on the shared grid add exactly
-    across chunks (DESIGN.md §2), so no rescale term exists.
+    s: (..., rows, n) raw scores (masked lanes already at -inf); m:
+    (..., rows, 1) the global row max from pass 1 — the shared quantization
+    anchor; valid: the live-lane mask, broadcast against s. Returns
+    (e, dden): the LUT-reconstructed unnormalized weights (masked lanes
+    zeroed) and this chunk's partial histogram denominator — integer counts
+    on the shared grid add exactly across chunks (DESIGN.md §2), so no
+    rescale term exists.
     """
     inv_delta = levels / (-clip)
     codes = jnp.clip(jnp.floor((s - m - clip) * inv_delta), 0, levels - 1).astype(jnp.int32)
@@ -109,7 +119,7 @@ def exaq_accumulate_stage(s, m, valid, *, levels: int, clip: float, lut: tuple[f
     for kk in range(1, levels):
         e = jnp.where(codes == kk, lut[kk], e)
     e = jnp.where(valid, e, 0.0)
-    dden = jnp.zeros((s.shape[0], 1), jnp.float32)
+    dden = jnp.zeros(s.shape[:-1] + (1,), jnp.float32)
     for kk in range(levels):
         cnt = jnp.sum(jnp.where(valid & (codes == kk), 1, 0).astype(jnp.int32),
                       axis=-1, keepdims=True)
@@ -148,12 +158,74 @@ def load_pool_block(ref, sc_ref, sub_ref, row, *, bs: int, kv_quant: bool, kv_in
     return x
 
 
+def load_pool_tile(buf, sl, head, sc_ref, sub_ref, rows, *, bs: int, kv_quant: bool,
+                   kv_int4: bool, n_sub: int, sub_bs: int):
+    """One kv head of a tile of pool blocks to dequantized fp32 rows — the
+    decode kernel's tile-wide twin of ``load_pool_block``, with the same
+    per-element arithmetic.
+
+    ``buf`` is a VMEM buffer (2, tb, KV, bs, W) of whole pool blocks and
+    ``sl`` its (dynamic) half; the result stacks the tb blocks' rows of
+    head ``head`` into (tb*bs, W). ``rows[b]`` is block b's flat
+    (block, kv-head) index into the scale planes: int8 rows are
+    ``codes * scale``; int4 rows are ``codes * (block_scale * sub_code /
+    15)`` per sub_bs-row group, in ``kv4_effective_scale``'s multiply order.
+    int4 codes come out *split*, not interleaved: lanes [0, W) hold the low
+    nibbles (even head dims), lanes [W, 2W) the high ones (odd dims) — the
+    caller lays q out the same way and restores the output's dim order, which
+    spares the kernel a lane shuffle per block."""
+    tb = buf.shape[1]
+    x = buf[sl, :, head]
+    if kv_int4:
+        xi = x.astype(jnp.int32).reshape(tb * bs, x.shape[-1])  # Mosaic shifts 32-bit lanes
+        x = jnp.concatenate([(xi & 0xF) - INT4_BIAS, (xi >> 4) - INT4_BIAS], axis=-1)
+    x = x.astype(jnp.float32).reshape(tb * bs, x.shape[-1])
+    if not (kv_quant or kv_int4):
+        return x
+    if kv_int4:
+        col = [sc_ref[r] * sub_ref[r * n_sub + g].astype(jnp.float32) * INV_SUB_LEVELS
+               * jnp.ones((sub_bs, 1), jnp.float32) for r in rows for g in range(n_sub)]
+    else:
+        col = [sc_ref[r] * jnp.ones((bs, 1), jnp.float32) for r in rows]
+    return x * jnp.concatenate(col, axis=0)
+
+
+# A tile is at most this many tokens (on a v5e, 512 beat 128, 256 and 1024
+# at the yi-6b.chat shape; PERF.md), and its K and V double buffers at most
+# this many VMEM bytes (v5e's default scoped VMEM limit is 16 MiB).
+_TILE_TOKENS = 512
+_TILE_VMEM_BYTES = 4 << 20
+# block copies the decode kernel issues per step of its copy loop
+_COPY_GROUP = 8
+
+
+def decode_tile_tokens(*, block_size: int, max_blocks: int, kv_heads: int, head_dim: int,
+                       kv_dtype) -> int:
+    """Tokens per tile of the decode kernel's live-bounded loop, for a pool
+    of ``kv_dtype`` (a jnp dtype, or ``"int4"`` for the packed pool).
+
+    The most whole blocks up to ``_TILE_TOKENS`` tokens whose K and V double
+    buffers — ``2 * 2 * kv_heads * block_size * width * itemsize`` bytes a
+    block, ``width`` the lane-padded last dim of the pool as the kernel
+    reads it — fit ``_TILE_VMEM_BYTES``, capped at the table width, at least
+    one block. Derived from shapes alone, so the engine's tile counters
+    (``decode_kv_tiles``) and the kernel agree."""
+    if kv_cache_is_int4(kv_dtype):
+        width, itemsize = _round_up(max(head_dim // 2, _LANES), _LANES), 1
+    else:
+        width, itemsize = _round_up(max(head_dim, _LANES), _LANES), jnp.dtype(kv_dtype).itemsize
+    per_block = 4 * kv_heads * block_size * width * itemsize
+    blocks = min(_TILE_TOKENS // block_size, _TILE_VMEM_BYTES // per_block, max_blocks)
+    return block_size * max(1, blocks)
+
+
 def _paged_decode_kernel(
     tables_ref,
     lens_ref,
     *refs,
     bs: int,
     mb: int,
+    tb: int,
     block_q: int,
     levels: int,
     clip: float,
@@ -165,73 +237,152 @@ def _paged_decode_kernel(
     n_sub: int = 0,
     sub_bs: int = 0,
 ):
-    """Grid (S, KV, 2*MB): chunks 0..MB-1 are the max pass, MB..2*MB-1 the
-    quantize+accumulate pass. Scratch (m, l, acc) carries across the chunk
-    axis; the BlockSpec index maps (not this body) steer the pool DMA.
-    ``kv_quant`` pools carry two extra scalar-prefetch refs — the
-    per-(block, kv-head) dequant scales (DESIGN.md §6); ``kv_int4`` pools
-    carry two more — the (N, KV, n_sub) sub-block scale codes — and their
-    K/V refs hold *packed* nibbles at half width (DESIGN.md §10)."""
+    """One program per slot, over all its kv heads. Two loops over the
+    slot's live tiles of ``tb`` pool blocks, bounded by ``kv_lens[slot]``:
+    the first reduces the global row max, the second quantizes on it and
+    accumulates. The pools stay in HBM (``memory_space=pl.ANY``); each tile's
+    live blocks are copied from the scalar-prefetched table into one half of
+    a double buffer while the other half is computed on. ``kv_quant`` pools
+    carry two extra scalar-prefetch refs — the per-(block, kv-head) dequant
+    scales (DESIGN.md §6); ``kv_int4`` pools carry two more — the
+    (N, KV, n_sub) sub-block scale codes — and their pools hold *packed*
+    nibbles at half width (DESIGN.md §10)."""
     if kv_int4:
-        (ksc_ref, vsc_ref, ksub_ref, vsub_ref,
-         q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref) = refs
+        ksc_ref, vsc_ref, ksub_ref, vsub_ref, *refs = refs
     elif kv_quant:
+        ksc_ref, vsc_ref, *refs = refs
         ksub_ref = vsub_ref = None
-        ksc_ref, vsc_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     else:
         ksc_ref = vsc_ref = ksub_ref = vsub_ref = None
-        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, k_sem, v_sem,
+     m_ref, l_ref, acc_ref) = refs
     slot = pl.program_id(0)
-    head = pl.program_id(1)
-    j = pl.program_id(2)
-    t = j % mb  # table entry this step touches (same in both passes)
-    kv_len = lens_ref[slot]
-    live = t * bs < kv_len
-    # the block whose payload sits in k_ref/v_ref this step (dead tails are
-    # pinned to the null block, whose scale is 0 — masked lanes anyway)
-    blk = jnp.where(live, tables_ref[slot, t], 0)
+    # scalar index math uses lax ops: jnp's floor division and modulo trace
+    # sign fix-ups that make the kernel slow to lower. A finished slot's
+    # stale length may pass the table by one token
+    div, rem = jax.lax.div, jax.lax.rem
+    kv_len = jnp.minimum(lens_ref[slot], mb * bs)
+    tile = tb * bs
+    n_tiles = div(kv_len + tile - 1, tile)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_BIG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def _n_live(i):
+        return jnp.minimum(tb, div(kv_len - i * tile + bs - 1, bs))
 
-    col = t * bs + jax.lax.broadcasted_iota(jnp.int32, (block_q, bs), 1)
-    valid = col < kv_len
+    def _start(hbm, buf, sem, i, sl):
+        # only tile i's live blocks move (a tile's dead rows are masked
+        # below), each whole: all kv heads in one contiguous copy. Whole
+        # groups of _COPY_GROUP go back to back (faster to issue than one
+        # a loop step), the rest one a step
+        n_live = _n_live(i)
 
-    def _load_kv(ref, sc_ref, sub_ref):
-        return load_pool_block(ref, sc_ref, sub_ref, blk * kv + head, bs=bs, kv_quant=kv_quant,
-                               kv_int4=kv_int4, n_sub=n_sub, sub_bs=sub_bs)
+        def copy(b):
+            blk = tables_ref[slot, i * tb + b]
+            pltpu.make_async_copy(hbm.at[blk], buf.at[sl, b], sem.at[sl]).start()
 
-    def _scores():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = _load_kv(k_ref, ksc_ref, ksub_ref)
+        def group(g, carry):
+            for u in range(_COPY_GROUP):
+                copy(g * _COPY_GROUP + u)
+            return carry
+
+        def single(b, carry):
+            copy(b)
+            return carry
+
+        n_full = div(n_live, _COPY_GROUP)
+        jax.lax.fori_loop(0, n_full, group, 0)
+        jax.lax.fori_loop(n_full * _COPY_GROUP, n_live, single, 0)
+
+    def _wait(hbm, buf, sem, i, sl):
+        # every copy moved one block's bytes onto this semaphore: wait as many
+        copy = pltpu.make_async_copy(hbm.at[0], buf.at[sl, 0], sem.at[sl])
+
+        def one(b, carry):
+            copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, _n_live(i), one, 0)
+
+    def _load(buf, sl, sc_ref, sub_ref, blocks):
+        # every kv head of the tile, (KV, tb*bs, W): the heads' query groups
+        # then run as one batched dot, not a loop
+        return jnp.stack([
+            load_pool_tile(buf, sl, h, sc_ref, sub_ref,
+                           None if blocks is None else [blk * kv + h for blk in blocks],
+                           bs=bs, kv_quant=kv_quant, kv_int4=kv_int4, n_sub=n_sub, sub_bs=sub_bs)
+            for h in range(kv)
+        ])
+
+    def _blocks(i):
+        # tile i's block ids for the scale planes (dead entries: the null block)
+        if not (kv_quant or kv_int4):
+            return None
+        return [jnp.where(b < _n_live(i), tables_ref[slot, jnp.minimum(i * tb + b, mb - 1)], 0)
+                for b in range(tb)]
+
+    def _scores(sl, i):
+        # (KV, block_q, tile) scores of tile i, masked past kv_len
+        k = _load(k_buf, sl, ksc_ref, ksub_ref, _blocks(i))
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q_ref[0].astype(jnp.float32), k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
         ) * scale
-        return jnp.where(valid, s, _NEG_BIG)
+        return jnp.where(_valid(i), s, _NEG_BIG)
 
-    @pl.when((j < mb) & live)
-    def _max_pass():
-        s = _scores()
+    def _valid(i):
+        return i * tile + jax.lax.broadcasted_iota(jnp.int32, (block_q, tile), 1) < kv_len
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_BIG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(n_tiles > 0)
+    def _first():
+        _start(k_hbm, k_buf, k_sem, 0, 0)
+
+    # K buffer halves alternate over the 2*n_tiles steps of both passes
+    # (pass 2's tile i is step n_tiles + i); V's over pass 2's tiles alone
+    def _max_step(i, carry):
+        sl = rem(i, 2)
+        # the next K tile: this pass's next, or pass 2's first after the last
+        _start(k_hbm, k_buf, k_sem, rem(i + 1, n_tiles), 1 - sl)
+
+        @pl.when(i + 1 == n_tiles)
+        def _next_pass():
+            _start(v_hbm, v_buf, v_sem, 0, 0)
+
+        _wait(k_hbm, k_buf, k_sem, i, sl)
+        s = _scores(sl, i)
         m_ref[...] = jnp.maximum(m_ref[...], jnp.max(s, axis=-1, keepdims=True))
+        return carry
 
-    @pl.when((j >= mb) & live)
-    def _acc_pass():
-        s = _scores()
-        m = m_ref[:, :1]  # global row max from pass 1 — shared quantization grid
-        e, dden = exaq_accumulate_stage(s, m, valid, levels=levels, clip=clip, lut=lut)
+    def _acc_step(i, carry):
+        sl = rem(n_tiles + i, 2)
+        vsl = rem(i, 2)
+
+        @pl.when(i + 1 < n_tiles)
+        def _next():
+            _start(k_hbm, k_buf, k_sem, i + 1, 1 - sl)
+            _start(v_hbm, v_buf, v_sem, i + 1, 1 - vsl)
+
+        _wait(k_hbm, k_buf, k_sem, i, sl)
+        _wait(v_hbm, v_buf, v_sem, i, vsl)
+        s = _scores(sl, i)
+        m = m_ref[:, :, :1]  # global row max from pass 1 — shared quantization grid
+        e, dden = exaq_accumulate_stage(s, m, _valid(i), levels=levels, clip=clip, lut=lut)
         l_ref[...] = l_ref[...] + dden
-        v = _load_kv(v_ref, vsc_ref, vsub_ref)
+        # rows past kv_len hold whatever the buffer held before: zero them so
+        # 0-weight x garbage (a NaN bit pattern) cannot reach the accumulator
+        row_live = (i * tile + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)) < kv_len
+        v = jnp.where(row_live, _load(v_buf, vsl, vsc_ref, vsub_ref, _blocks(i)), 0.0)
         acc_ref[...] += jax.lax.dot_general(
-            e, v, (((1,), (0,)), ((), ())),
+            e, v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
+        return carry
 
-    @pl.when(j == 2 * mb - 1)
-    def _finalize():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30))[None, None].astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_tiles, _max_step, 0)
+    jax.lax.fori_loop(0, n_tiles, _acc_step, 0)
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :, :1], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -304,8 +455,10 @@ def exaq_paged_decode_attention(
             ppad = ((0, 0), (0, 0), (0, 0), (0, p_pad - D // 2))
             pool_k = jnp.pad(pool_k, ppad)
             pool_v = jnp.pad(pool_v, ppad)
-        if d_pad != D:
-            q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, d_pad - D)))
+        # the kernel unpacks low nibbles (even dims) to lanes [0, p_pad) and
+        # high nibbles (odd dims) to [p_pad, 2*p_pad): split q's dims the same way
+        qpad = ((0, 0), (0, 0), (0, 0), (0, p_pad - D // 2))
+        q = jnp.concatenate([jnp.pad(q[..., 0::2], qpad), jnp.pad(q[..., 1::2], qpad)], axis=-1)
     else:
         n_sub = sub_bs = 0
         d_pad = _round_up(max(D, _LANES), _LANES)
@@ -318,24 +471,14 @@ def exaq_paged_decode_attention(
             pool_k = jnp.pad(pool_k, pad)
             pool_v = jnp.pad(pool_v, pad)
 
+    tb = decode_tile_tokens(block_size=bs, max_blocks=MB, kv_heads=KV, head_dim=D,
+                            kv_dtype="int4" if kv_int4 else pool_k.dtype) // bs
     tables = block_tables.astype(jnp.int32)
     lens = kv_lens.astype(jnp.int32)
     lut = tuple(float(x) for x in params.lut_np())
 
-    def _k_index(s, h, j, tbl, lns, *sc):
-        # dead tail -> null block; consecutive identical indices are a
-        # single DMA, so dead chunks cost ~nothing
-        t = j % MB
-        return (jnp.where(t * bs < lns[s], tbl[s, t], 0), h, 0, 0)
-
-    def _v_index(s, h, j, tbl, lns, *sc):
-        # V is only consumed by the accumulate pass; pin the max pass (and
-        # dead chunks) to the null block so V moves over HBM exactly once
-        t = j % MB
-        return (jnp.where((j >= MB) & (t * bs < lns[s]), tbl[s, t], 0), h, 0, 0)
-
-    def _q_index(s, h, j, tbl, lns, *sc):
-        return (s, h, 0, 0)
+    def _q_index(s, *prefetch):
+        return (s, 0, 0, 0)
 
     # the dequant scales ride the scalar-prefetch channel and the kernel
     # indexes them by the same prefetched table entry that steered the DMA.
@@ -349,24 +492,29 @@ def exaq_paged_decode_attention(
                      v_scale.astype(jnp.float32).reshape(-1))
     if kv_int4:
         prefetch += (k_sub.astype(jnp.int32).reshape(-1), v_sub.astype(jnp.int32).reshape(-1))
+    buf = pltpu.VMEM((2, tb, KV, bs, kv_width), pool_k.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(S, KV, 2 * MB),
+        grid=(S,),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d_pad), _q_index),
-            pl.BlockSpec((1, 1, bs, kv_width), _k_index),
-            pl.BlockSpec((1, 1, bs, kv_width), _v_index),
+            pl.BlockSpec((1, KV, block_q, d_pad), _q_index),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d_pad), _q_index),
+        out_specs=pl.BlockSpec((1, KV, block_q, d_pad), _q_index),
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d_pad), jnp.float32),
+            buf,
+            buf,
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((KV, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((KV, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((KV, block_q, d_pad), jnp.float32),
         ],
     )
     kern = functools.partial(
         _paged_decode_kernel,
-        bs=bs, mb=MB, block_q=block_q,
+        bs=bs, mb=MB, tb=tb, block_q=block_q,
         levels=params.levels, clip=float(params.clip), lut=lut, scale=float(scale),
         kv=KV, kv_quant=kv_quant, kv_int4=kv_int4, n_sub=n_sub, sub_bs=sub_bs,
     )
@@ -374,14 +522,15 @@ def exaq_paged_decode_attention(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, KV, block_q, d_pad), jnp.float32),
-        # only the chunk axis carries scratch state; (slot, kv_head) programs
+        # every program runs both passes over its own slot's tiles; programs
         # are independent and may partition across cores
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*prefetch, q, pool_k, pool_v)
-    return out[:, :, :group, :D].reshape(S, H, 1, D)
+    out = out[:, :, :group]
+    if kv_int4:  # back from split (even dims, odd dims) lanes to head-dim order
+        out = jnp.stack([out[..., : D // 2], out[..., p_pad : p_pad + D // 2]], axis=-1)
+    return out[..., :D].reshape(S, H, 1, D)
 
 
 KV_DTYPE_BYTES = {"fp32": 4, "bf16": 2, "int8": 1, "int4": 0.5}

@@ -78,6 +78,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation as span
 
+from repro.kernels.exaq_paged_attention import decode_tile_tokens
 from repro.kernels.kv_codec import kv_cache_is_quantized
 from repro.runtime import sampling as smp
 from repro.runtime.device_step import PagedDeviceStep, SlotDeviceStep
@@ -88,6 +89,7 @@ from repro.runtime.engine_core import (
     HostCore,
     Request,
     _bucket,
+    decode_tile_counts,
     _PagedSlot,
     _Slot,
 )
@@ -478,6 +480,18 @@ class PagedEngine(EngineCore, Engine):
             eos_id=config.eos_id, cache_dtype=cache_dtype, mesh=mesh,
         )
         self._bind_device_step()
+        # tokens per tile of the fused decode kernel, for the decode_kv_tiles
+        # counters; None where this engine's decode never runs that kernel
+        self._decode_tile = None
+        if (cfg.quant.use_fused_kernel and cfg.quant.softmax_impl == "exaq"
+                and not cfg.attention_free):
+            kv = cfg.num_kv_heads
+            tp = 1 if mesh is None else mesh.shape.get("model", 1)
+            self._decode_tile = decode_tile_tokens(
+                block_size=config.block_size, max_blocks=self.blocks_per_table,
+                kv_heads=kv // tp if kv % tp == 0 else kv,
+                head_dim=cfg.resolved_head_dim, kv_dtype=cache_dtype,
+            )
         self._key = jax.random.PRNGKey(config.seed)
         self._pool = self._dev.init_pool()
         # raw jitted (pool, src, dst) -> pool CoW copy; tests drive it directly
@@ -588,9 +602,17 @@ class PagedEngine(EngineCore, Engine):
         self.stats["decode_time"] += time.perf_counter() - t0
         self._pool = pool
         was_active = self._active
+        lens_in = self.kv_lens
         with span("engine.absorb"):
-            return self._absorb_chunk(tokens, lens, active, budget, emitted, masks,
-                                      was_active)
+            n_out = self._absorb_chunk(tokens, lens, active, budget, emitted, masks,
+                                       was_active)
+            if self._decode_tile is not None:
+                walked, full = decode_tile_counts(
+                    lens_in, masks, tile=self._decode_tile,
+                    table_tokens=self.blocks_per_table * self.block_size)
+                self.stats["decode_kv_tiles"] += walked
+                self.stats["decode_kv_tiles_full"] += full
+        return n_out
 
     # ------------------------------------------------- speculative decoding
 

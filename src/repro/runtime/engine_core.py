@@ -306,6 +306,23 @@ def _bucket(n: int, lo: int = 16) -> int:
     return b
 
 
+def decode_tile_counts(kv_lens, masks, *, tile: int, table_tokens: int) -> tuple[int, int]:
+    """Tiles the fused paged-decode kernel walks over one decode chunk, and
+    the tiles a walk over every table entry would take.
+
+    ``kv_lens`` (S,) are the slots' lengths as the chunk was dispatched and
+    ``masks`` (steps, S) which slots were active at each step. Step t
+    attends over ``kv_lens + 1 + (tokens the slot emitted before t)`` tokens
+    of every slot, active or not (the step passes every slot's length), in
+    ``tile``-token tiles; a full walk takes ``cdiv(table_tokens, tile)``
+    tiles per slot and step. Returns ``(walked, full)``."""
+    masks = np.asarray(masks, np.int64)
+    before = np.cumsum(masks, axis=0) - masks
+    kv = np.asarray(kv_lens, np.int64)[None, :] + 1 + before
+    walked = int(np.sum(-(-kv // tile)))
+    return walked, masks.size * -(-table_tokens // tile)
+
+
 class HostCore:
     """Slot-level host scheduler state shared by both engines (no jax)."""
 
@@ -686,7 +703,8 @@ class EngineCore(HostCore):
         self.state_blocks = state_blocks
 
         self.stats.update(prompt_tokens=0, prefix_hit_tokens=0,
-                          prefill_tokens=0, prefill_chunks=0, preemptions=0)
+                          prefill_tokens=0, prefill_chunks=0, preemptions=0,
+                          decode_kv_tiles=0, decode_kv_tiles_full=0)
         # CoW device copies planned but not yet performed: (src, dst) pairs in
         # the order they must execute (see class docstring)
         self.pending_copies: list[tuple[int, int]] = []

@@ -23,6 +23,7 @@ from repro.runtime.engine_core import (  # noqa: E402
     PrefillChunkPlan,
     Request,
     _bucket,
+    decode_tile_counts,
 )
 from repro.runtime.kv_pool import NULL_BLOCK, PoolExhausted, PoolStats  # noqa: E402
 
@@ -323,3 +324,22 @@ def test_request_uses_host_greedy_default():
     assert r.sampling.temperature == 0.0
     assert r.sampling.top_k == 0
     assert r.sampling.top_p == 1.0
+
+
+# ------------------------------------------------------ decode tile counters
+
+@pytest.mark.parametrize("steps", [1, 3])
+def test_decode_tile_counts_follow_lengths(steps):
+    """decode_kv_tiles counts cdiv(kv_len, tile) for every slot of every
+    step — idle slots at their stale length plus the step's own token,
+    active ones growing one token a step until their mask drops —
+    and decode_kv_tiles_full counts a whole-table walk."""
+    lens = np.array([0, 3, 4, 7])  # slot 0 idle; tile 4; a 10-token table
+    masks = np.array([[0, 1, 1, 1],   # slot 2 finishes after step 0,
+                      [0, 1, 0, 1],   # slot 3 after step 1
+                      [0, 1, 0, 0]])[:steps]
+    walked, full = decode_tile_counts(lens, masks, tile=4, table_tokens=10)
+    # step 0 reads [1, 4, 5, 8] tokens: 1+1+2+2 tiles; step 1 [1, 5, 6, 9]:
+    # 1+2+2+3; step 2 [1, 6, 6, 10]: 1+2+2+3
+    assert walked == {1: 6, 3: 6 + 8 + 8}[steps]
+    assert full == steps * 4 * 3

@@ -14,7 +14,7 @@ import pytest
 
 from repro.core import exaq_params
 from repro.kernels import ops
-from repro.kernels.exaq_paged_attention import paged_decode_bytes_model
+from repro.kernels.exaq_paged_attention import decode_tile_tokens, paged_decode_bytes_model
 
 RNG = np.random.default_rng(42)
 
@@ -280,6 +280,67 @@ def test_int4_requires_sub_planes_and_fp_forbids_them(quantize_pool_int4):
                                    use_kernel=True)  # fused missing v_sub
 
 
+# ------------------------------------------------- live-bounded tile loop
+
+# The kernel walks each slot's live tokens in tiles of decode_tile_tokens():
+# 512 tokens (8 blocks) at bs 64. A 10-entry table is 1.25 tiles, so the
+# last tile runs past the table's end and the clamp/mask paths both fire.
+TILE_BS, TILE_MB = 64, 10
+
+
+@pytest.mark.parametrize("group", [1, 4, 8])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "int4"])
+def test_fused_tile_edges_match_gather(kv_dtype, group, quantize_pool, quantize_pool_int4):
+    """kv_lens at T-1, T, T+1, 1 and the full table, over a table width T/bs
+    does not divide, with every slot sharing slot 0's first 5 blocks (the
+    prefix-cache layout): the fused kernel matches the (dequantizing) gather
+    oracle to <= 1e-5 for every pool dtype and GQA group."""
+    KV, D = 2, 64
+    H = KV * group
+    dtype = {"bf16": jnp.bfloat16, "int8": jnp.int8, "int4": "int4"}[kv_dtype]
+    T = decode_tile_tokens(block_size=TILE_BS, max_blocks=TILE_MB, kv_heads=KV, head_dim=D,
+                           kv_dtype=dtype)
+    assert T == 512 and TILE_MB % (T // TILE_BS)
+    lens = jnp.asarray([T - 1, T, T + 1, 1, TILE_MB * TILE_BS], jnp.int32)
+    S = lens.shape[0]
+    p = exaq_params(1.5, 2)
+    q = jnp.asarray(RNG.normal(0, 1, (S, H, 1, D)), jnp.float32)
+    pk, pv, tbl = _pool_setup(S, KV, TILE_BS, TILE_MB, D, seed=30 + group)
+    tbl = tbl.at[1:, :5].set(tbl[0, :5])
+    planes = {}
+    if kv_dtype == "bf16":
+        pk, pv = pk.astype(jnp.bfloat16), pv.astype(jnp.bfloat16)
+    elif kv_dtype == "int8":
+        pk, pv, planes["k_scale"], planes["v_scale"] = quantize_pool(pk, pv)
+    else:
+        pk, pv, *scales = quantize_pool_int4(pk, pv)
+        planes = dict(zip(("k_scale", "v_scale", "k_sub", "v_sub"), scales))
+    got = ops.paged_decode_attention(q, pk, pv, tbl, lens, p, D**-0.5, use_kernel=True,
+                                     **planes)
+    want = ops.paged_decode_attention(q, pk, pv, tbl, lens, p, D**-0.5, use_kernel=False,
+                                      **planes)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "int4"])
+def test_decode_tile_rule(kv_dtype):
+    """The tile follows the shapes: 512 tokens where the double buffers fit
+    4 MiB, fewer kv heads' worth of bytes for wide pools, never past the
+    table, at least one block."""
+    dtype = {"bf16": jnp.bfloat16, "int8": jnp.int8, "int4": "int4"}[kv_dtype]
+
+    def tile(**kw):
+        args = dict(block_size=16, max_blocks=256, kv_heads=4, head_dim=128, kv_dtype=dtype)
+        return decode_tile_tokens(**{**args, **kw})
+
+    assert tile() == 512  # the yi-6b.chat shape
+    assert tile(max_blocks=8) == 128  # capped at the table
+    assert tile(block_size=1024, max_blocks=4) == 1024  # one block at least
+    # 32 kv heads at bf16 need 512 KiB of double buffers a block: 8 fit
+    assert tile(kv_heads=32) == {"bf16": 128, "int8": 256, "int4": 256}[kv_dtype]
+
+
 # --------------------------------------------------------- gather dead tails
 
 def test_gather_block_kv_clamps_dead_tail_to_null_block():
@@ -398,6 +459,11 @@ def test_paged_engine_fused_matches_gather_greedy():
         uids = [eng.submit(p, g) for p, (_, g) in zip(prompts, spec)]
         res = eng.run()
         outs[fused] = [res[u].tokens for u in uids]
+        # the tile counters count the fused kernel's walk only; a 48-token
+        # table is one tile, so every slot of every step walks exactly one
+        st = eng.stats
+        steps = st["decode_steps"] if fused else 0
+        assert st["decode_kv_tiles"] == st["decode_kv_tiles_full"] == 2 * steps
     assert outs[True] == outs[False]
 
 
@@ -425,6 +491,11 @@ def test_paged_engine_quantized_fused_matches_gather_greedy(cache_dtype):
         uids = [eng.submit(p, g) for p, (_, g) in zip(prompts, spec)]
         res = eng.run()
         outs[fused] = [res[u].tokens for u in uids]
+        # the tile counters count the fused kernel's walk only; a 48-token
+        # table is one tile, so every slot of every step walks exactly one
+        st = eng.stats
+        steps = st["decode_steps"] if fused else 0
+        assert st["decode_kv_tiles"] == st["decode_kv_tiles_full"] == 2 * steps
     assert outs[True] == outs[False]
 
 

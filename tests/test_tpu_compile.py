@@ -5,7 +5,9 @@ run in interpret mode, which accepts any tiling and any amount of SMEM/VMEM.
 Here the TPU compiler that ships with jaxlib compiles the fused paged decode
 and paged prefill kernels for a v5e that is described, not attached, at
 yi-6b serving widths (32 q-heads, 4 kv-heads, head_dim 128, 16-token blocks,
-a 1024-block pool) for every pool dtype, plus zamba2's head_dim 80. Nothing
+a 1024-block pool) for every pool dtype, plus zamba2's head_dim 80, and the
+decode kernel at the yi-6b.chat deployment's shape (16 slots, 256-entry
+tables, a 1400-block pool) for every pool dtype. Nothing
 runs: a pass means the chip's compiler accepts the kernel, and the compiled
 text holds the Mosaic kernel (``tpu_custom_call``).
 
@@ -29,6 +31,8 @@ from repro.kernels.kv_codec import kv4_num_sub
 H, KV, BS, N_BLOCKS = 32, 4, 16, 1024
 SLOTS, MAX_BLOCKS = 4, 128  # 2048-token windows
 CHUNK = 32  # EngineConfig's default prefill chunk
+# the yi-6b.chat deployment: 16 slots, max_seq 4096, a 22,384-token pool
+SERVED_SLOTS, SERVED_MAX_BLOCKS, SERVED_N_BLOCKS = 16, 256, 22384 // BS + 1
 
 CASES = [("bf16", 128), ("int8", 128), ("int4", 128), ("bf16", 80)]
 CASE_IDS = [f"{kv}-d{dh}" for kv, dh in CASES]
@@ -53,19 +57,19 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _pool_args(kv_dtype: str, dh: int, sharding) -> tuple[tuple, dict]:
+def _pool_args(kv_dtype: str, dh: int, sharding, n_blocks: int = N_BLOCKS) -> tuple[tuple, dict]:
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     if kv_dtype == "int4":
-        pool = sds((N_BLOCKS, KV, BS, dh // 2), jnp.uint8)
+        pool = sds((n_blocks, KV, BS, dh // 2), jnp.uint8)
     else:
-        pool = sds((N_BLOCKS, KV, BS, dh), {"bf16": jnp.bfloat16, "int8": jnp.int8}[kv_dtype])
+        pool = sds((n_blocks, KV, BS, dh), {"bf16": jnp.bfloat16, "int8": jnp.int8}[kv_dtype])
     planes = {}
     if kv_dtype in ("int8", "int4"):
-        planes["k_scale"] = planes["v_scale"] = sds((N_BLOCKS, KV), jnp.float32)
+        planes["k_scale"] = planes["v_scale"] = sds((n_blocks, KV), jnp.float32)
     if kv_dtype == "int4":
-        planes["k_sub"] = planes["v_sub"] = sds((N_BLOCKS, KV, kv4_num_sub(BS)), jnp.uint8)
+        planes["k_sub"] = planes["v_sub"] = sds((n_blocks, KV, kv4_num_sub(BS)), jnp.uint8)
     return (pool, pool), planes
 
 
@@ -81,6 +85,20 @@ def test_paged_decode_compiles_for_v5e(one_chip, kv_dtype, dh):
     lens = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
     compiled = exaq_paged_decode_attention.lower(
         q, pk, pv, tables, lens, exaq_params(2.0, 2), dh**-0.5, **planes
+    ).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "int4"])
+def test_paged_decode_compiles_for_v5e_at_served_shape(one_chip, kv_dtype):
+    """The served decode shape: 16 slots, 256-entry tables, so the kernel's
+    512-token tiles and their VMEM double buffers are what the cell runs."""
+    (pk, pv), planes = _pool_args(kv_dtype, 128, one_chip, SERVED_N_BLOCKS)
+    q = jax.ShapeDtypeStruct((SERVED_SLOTS, H, 1, 128), jnp.bfloat16, sharding=one_chip)
+    tables = jax.ShapeDtypeStruct((SERVED_SLOTS, SERVED_MAX_BLOCKS), jnp.int32, sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((SERVED_SLOTS,), jnp.int32, sharding=one_chip)
+    compiled = exaq_paged_decode_attention.lower(
+        q, pk, pv, tables, lens, exaq_params(2.0, 2), 128**-0.5, **planes
     ).compile()
     _assert_kernel(compiled)
 
