@@ -19,6 +19,8 @@ class MoEConfig:
     d_expert: int = 0           # expert FFN width (fine-grained MoE)
     capacity_factor: float = 1.25
     group_size: int = 256       # dispatch group (bounds the one-hot temp)
+    first_dense: int = 0        # leading layers with a dense d_ff MLP (hf first_k_dense_replace)
+    norm_topk: bool = True      # renormalise the top-k gates to sum to 1 (hf norm_topk_prob)
 
 
 @dataclass(frozen=True)
@@ -67,6 +69,8 @@ class ModelConfig:
     attn_block_q: int = 512      # q-block size of the streamed attention scan
     attn_scores_bf16: bool = False  # materialize attention scores in bf16 (EXAQ makes this ~free)
     source: str = ""             # provenance note
+    expert_parallel: int = 1     # moe: chips that share each expert layer's routed experts
+    expert_shard: int = 0        # moe: which of them this program is (holds a contiguous range)
 
     @property
     def padded_vocab(self) -> int:
@@ -78,6 +82,17 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // max(self.num_heads, 1)
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """[lo, hi): the routed experts this program holds. Expert parallelism
+        over ``expert_parallel`` chips gives shard ``s`` the s-th contiguous
+        range; the router still scores all ``moe.num_experts``."""
+        E, ep, s = self.moe.num_experts, self.expert_parallel, self.expert_shard
+        if ep < 1 or E % ep or not 0 <= s < ep:
+            raise ValueError(f"{E} experts cannot be shard {s} of {ep}-way expert parallelism")
+        n = E // ep
+        return s * n, (s + 1) * n
 
     @property
     def attention_free(self) -> bool:
@@ -120,6 +135,8 @@ class ModelConfig:
                 d_expert=64,
                 capacity_factor=2.0,
                 group_size=32,
+                first_dense=min(self.moe.first_dense, 1),
+                norm_topk=self.moe.norm_topk,
             )
         small.update(overrides)
         return dataclasses.replace(self, **small)

@@ -8,7 +8,11 @@ as scan xs) so HLO size is O(1) in depth — required for tractable 512-device
 SPMD compiles. Families:
 
   dense | vlm    : [attn -> mlp] x L, optional patch-embed prefix (stub frontend)
-  moe            : [attn -> moe_ffn] x L (+ shared experts)
+  moe            : [attn -> moe_ffn] x L (+ shared experts); with
+                   ``moe.first_dense`` = n, the first n layers take a dense
+                   mlp instead (DeepSeekMoE): one scan over all L layers
+                   whose feed-forward weights ("dense_mlp" or "moe",
+                   stacked apart) are picked by layer index
   ssm            : [mamba2] x L (attention-free; EXAQ n/a)
   hybrid (zamba2): groups of `hybrid_period` mamba blocks + ONE weight-shared
                    attention block applied on concat(h, h0) after each group
@@ -101,7 +105,9 @@ class Model:
                 "frontend_proj": truncated_normal_init(keys[2], (cfg.frontend_dim, cfg.d_model), cfg.frontend_dim**-0.5, dtype)
             }
         fam = cfg.family
-        if fam in ("dense", "vlm", "moe"):
+        if fam == "moe":
+            params.update(self._init_moe_stack(keys[3], dtype))
+        elif fam in ("dense", "vlm"):
             params["layers"] = self._init_decoder_stack(keys[3], cfg.num_layers, dtype)
         elif fam == "ssm":
             params["layers"] = self._init_ssm_stack(keys[3], cfg.num_layers, dtype)
@@ -130,15 +136,85 @@ class Model:
             "ln1": jnp.ones((cfg.d_model,), dtype),
             "ln2": jnp.ones((cfg.d_model,), dtype),
             "attn": attn.init_attention(k1, cfg, dtype=dtype),
+            "mlp": init_gated_mlp(k2, cfg.d_model, cfg.d_ff, dtype),
         }
-        if cfg.moe is not None:
-            p["moe"] = moe.init_moe(k2, cfg, dtype)
-        else:
-            p["mlp"] = init_gated_mlp(k2, cfg.d_model, cfg.d_ff, dtype)
         return p
 
     def _init_decoder_stack(self, key, n, dtype):
         return jax.vmap(lambda k: self._init_decoder_layer(k, dtype))(jax.random.split(key, n))
+
+    @property
+    def _first_dense(self) -> int:
+        """Leading dense layers of a MoE stack."""
+        return self.cfg.moe.first_dense if self.cfg.moe is not None else 0
+
+    def _init_moe_stack(self, key, dtype):
+        """A MoE stack: "layers" holds every layer's norms and attention
+        (stacked over all L), "dense_mlp" the ``first_dense`` leading layers'
+        MLPs (possibly none) and "moe" the other layers' expert layers."""
+        cfg = self.cfg
+        nd = self._first_dense
+        k1, k2, k3 = jax.random.split(key, 3)
+
+        def norms_attn(k):
+            return {"ln1": jnp.ones((cfg.d_model,), dtype), "ln2": jnp.ones((cfg.d_model,), dtype),
+                    "attn": attn.init_attention(k, cfg, dtype=dtype)}
+
+        return {
+            "layers": jax.vmap(norms_attn)(jax.random.split(k1, cfg.num_layers)),
+            "dense_mlp": jax.vmap(lambda k: init_gated_mlp(k, cfg.d_model, cfg.d_ff, dtype))(
+                jax.random.split(k2, nd)),
+            "moe": jax.vmap(lambda k: moe.init_moe(k, cfg, dtype))(
+                jax.random.split(k3, cfg.num_layers - nd)),
+        }
+
+    def _stack(self, params):
+        """The decoder stack as scan inputs; in a MoE model each layer also
+        carries its ``index``, which picks its feed-forward weights."""
+        if self.cfg.moe is None:
+            return params["layers"]
+        return dict(params["layers"], index=jnp.arange(self.cfg.num_layers, dtype=jnp.int32))
+
+    def _ffn_infer(self, params, lp, x, rows=None):
+        """Layer ``lp``'s feed-forward on its normed input ``x``: the dense
+        MLP, or in a MoE model the leading dense layer or the expert layer
+        that the layer's index names. With ``rows`` (live rows, MoE only) it
+        also returns the held experts' routed-row counts (zeros for a dense
+        layer)."""
+        cfg = self.cfg
+        if cfg.moe is None:
+            return gated_mlp(lp["mlp"], x)
+        nd, i = self._first_dense, lp["index"]
+
+        def sparse(x):
+            return moe.moe_ffn_infer(_layer_at(params["moe"], jnp.maximum(i - nd, 0)), x, cfg, rows)
+
+        def dense(x):
+            f = gated_mlp(_layer_at(params["dense_mlp"], jnp.minimum(i, nd - 1)), x)
+            if rows is None:
+                return f
+            lo, hi = cfg.held_experts
+            return f, jnp.zeros((hi - lo,), jnp.int32)
+
+        return jax.lax.cond(i < nd, dense, sparse, x) if nd else sparse(x)
+
+    def _ffn_train(self, params, lp, x):
+        """Training twin of ``_ffn_infer``: (out, load-balance loss, z loss),
+        the losses zero for a dense layer."""
+        cfg = self.cfg
+        if cfg.moe is None:
+            return gated_mlp(lp["mlp"], x), 0.0, 0.0
+        nd, i = self._first_dense, lp["index"]
+
+        def sparse(x):
+            f, aux = moe.moe_ffn(_layer_at(params["moe"], jnp.maximum(i - nd, 0)), x, cfg)
+            return f, aux["moe_lb"], aux["moe_z"]
+
+        def dense(x):
+            zero = jnp.zeros((), jnp.float32)
+            return gated_mlp(_layer_at(params["dense_mlp"], jnp.minimum(i, nd - 1)), x), zero, zero
+
+        return jax.lax.cond(i < nd, dense, sparse, x) if nd else sparse(x)
 
     def _init_ssm_layer(self, key, dtype):
         cfg = self.cfg
@@ -228,20 +304,19 @@ class Model:
                 lp, clip = xs
                 a = attn.attention_train(lp["attn"], rmsnorm(h, lp["ln1"], cfg.norm_eps), cfg, statics, clip)
                 h = h + a
+                f, lb, z = self._ffn_train(params, lp, rmsnorm(h, lp["ln2"], cfg.norm_eps))
                 if cfg.moe is not None:
-                    f, moe_aux = moe.moe_ffn(lp["moe"], rmsnorm(h, lp["ln2"], cfg.norm_eps), cfg)
-                    aux_lb = aux_lb + moe_aux["moe_lb"]
-                    aux_z = aux_z + moe_aux["moe_z"]
-                else:
-                    f = gated_mlp(lp["mlp"], rmsnorm(h, lp["ln2"], cfg.norm_eps))
+                    aux_lb = aux_lb + lb
+                    aux_z = aux_z + z
                 h = shard_activation(h + f, "btd")
                 return (h, aux_lb, aux_z), None
 
             (h, lb, z), _ = jax.lax.scan(
-                self._remat(body), (h, 0.0, 0.0), (params["layers"], qstate["attn_clip"])
+                self._remat(body), (h, 0.0, 0.0), (self._stack(params), qstate["attn_clip"])
             )
             if cfg.moe is not None:
-                aux = {"moe_lb": lb / cfg.num_layers, "moe_z": z / cfg.num_layers}
+                n_moe = cfg.num_layers - self._first_dense
+                aux = {"moe_lb": lb / n_moe, "moe_z": z / n_moe}
         elif fam == "ssm":
             def body(h, lp):
                 out, _ = mamba2.mamba_forward(
@@ -344,13 +419,10 @@ class Model:
             sigma, smin = attn.attention_score_stats(lp["attn"], x, cfg)
             a = attn.attention_train(lp["attn"], x, cfg, statics, jnp.float32(-1.0))
             h = h + a
-            if cfg.moe is not None:
-                f, _ = moe.moe_ffn(lp["moe"], rmsnorm(h, lp["ln2"], cfg.norm_eps), cfg)
-            else:
-                f = gated_mlp(lp["mlp"], rmsnorm(h, lp["ln2"], cfg.norm_eps))
+            f, _, _ = self._ffn_train(params, lp, rmsnorm(h, lp["ln2"], cfg.norm_eps))
             return h + f, (sigma, smin)
 
-        _, (sigmas, mins) = jax.lax.scan(body, h, params["layers"])
+        _, (sigmas, mins) = jax.lax.scan(body, h, self._stack(params))
         return {"attn_sigma": sigmas, "attn_min": mins}
 
     def qstate_from_stats(self, stats: dict) -> dict[str, jnp.ndarray]:
@@ -535,13 +607,10 @@ class Model:
                 lp, clip = xs
                 a, (kh, vh) = attn.attention_prefill(lp["attn"], rmsnorm(h, lp["ln1"], cfg.norm_eps), cfg, statics, clip)
                 h = h + a
-                if cfg.moe is not None:
-                    f = moe.moe_ffn_infer(lp["moe"], rmsnorm(h, lp["ln2"], cfg.norm_eps), cfg)
-                else:
-                    f = gated_mlp(lp["mlp"], rmsnorm(h, lp["ln2"], cfg.norm_eps))
+                f = self._ffn_infer(params, lp, rmsnorm(h, lp["ln2"], cfg.norm_eps))
                 return shard_activation(h + f, "btd"), (kh, vh)
 
-            h, (ks, vs) = jax.lax.scan(body, h, (params["layers"], qstate["attn_clip"]))
+            h, (ks, vs) = jax.lax.scan(body, h, (self._stack(params), qstate["attn_clip"]))
             cache = dict(cache)
             cache["k"] = jax.lax.dynamic_update_slice_in_dim(
                 cache["k"], ks.astype(cache["k"].dtype), 0, axis=3
@@ -652,13 +721,10 @@ class Model:
                 lp["attn"], rmsnorm(h, lp["ln1"], cfg.norm_eps), cfg, statics, clip, ck, cv, lens
             )
             h = h + a
-            if cfg.moe is not None:
-                f = moe.moe_ffn_infer(lp["moe"], rmsnorm(h, lp["ln2"], cfg.norm_eps), cfg)
-            else:
-                f = gated_mlp(lp["mlp"], rmsnorm(h, lp["ln2"], cfg.norm_eps))
+            f = self._ffn_infer(params, lp, rmsnorm(h, lp["ln2"], cfg.norm_eps))
             return h + f, (nk, nv)
 
-        h, (nk, nv) = jax.lax.scan(body, h, (params["layers"], qstate["attn_clip"], cache["k"], cache["v"]))
+        h, (nk, nv) = jax.lax.scan(body, h, (self._stack(params), qstate["attn_clip"], cache["k"], cache["v"]))
         new_cache["k"], new_cache["v"] = nk, nv
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
         logits = jnp.einsum("bd,dv->bv", h[:, -1], params["head"].astype(h.dtype))
@@ -679,7 +745,9 @@ class Model:
         ``cfg.quant.use_fused_kernel`` + exaq, every layer's attention runs
         the fused Pallas paged-decode kernel (block-table-indexed pool loads,
         no HBM gather — DESIGN.md §3); otherwise the gather-then-dispatch
-        reference. Returns (logits (S, V), new_pool).
+        reference. Returns (logits (S, V), new_pool); a MoE model adds a
+        third value, (L, held experts) int32: the live rows each layer
+        routed to each held expert (zeros for a leading dense layer).
 
         ssm / hybrid families route to the StatePool decode branch
         (DESIGN.md §13), which needs the kw-only ``block_size`` (the pure
@@ -699,6 +767,8 @@ class Model:
         int4 = pool["k"].dtype == jnp.uint8
         quantized = int4 or pool["k"].dtype == jnp.int8
 
+        routed = cfg.moe is not None
+
         def body(h, xs):
             lp, clip, pk, pv, *sc = xs
             a, nkv = attn.attention_decode_paged(
@@ -706,20 +776,23 @@ class Model:
                 pk, pv, block_tables, lens, active, *sc,
             )
             h = h + a
-            if cfg.moe is not None:
-                f = moe.moe_ffn_infer(lp["moe"], rmsnorm(h, lp["ln2"], cfg.norm_eps), cfg)
-            else:
-                f = gated_mlp(lp["mlp"], rmsnorm(h, lp["ln2"], cfg.norm_eps))
-            return h + f, nkv
+            x = rmsnorm(h, lp["ln2"], cfg.norm_eps)
+            if not routed:
+                return h + self._ffn_infer(params, lp, x), nkv
+            f, counts = self._ffn_infer(params, lp, x, rows=active[:, None])
+            return h + f, (nkv, counts)
 
         keys = ("k", "v") + (("k_scale", "v_scale") if quantized else ()) \
             + (("k_sub", "v_sub") if int4 else ())
-        xs = (params["layers"], qstate["attn_clip"]) + tuple(pool[k] for k in keys)
-        h, nkv = jax.lax.scan(body, h, xs)
+        xs = (self._stack(params), qstate["attn_clip"]) + tuple(pool[k] for k in keys)
+        h, ys = jax.lax.scan(body, h, xs)
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
         logits = jnp.einsum("bd,dv->bv", h[:, -1], params["head"].astype(h.dtype))
         logits = self._mask_padded_vocab(logits)
-        return logits, dict(zip(keys, nkv))
+        if not routed:
+            return logits, dict(zip(keys, ys))
+        nkv, counts = ys
+        return logits, dict(zip(keys, nkv)), counts
 
     def _decode_step_paged_state(self, params, h, pool, block_tables, lens, active,
                                  qstate, statics, block_size):
@@ -854,15 +927,12 @@ class Model:
                 pk, pv, block_table, start, blk_t, off_t, *sc,
             )
             h = h + a
-            if cfg.moe is not None:
-                f = moe.moe_ffn_infer(lp["moe"], rmsnorm(h, lp["ln2"], cfg.norm_eps), cfg)
-            else:
-                f = gated_mlp(lp["mlp"], rmsnorm(h, lp["ln2"], cfg.norm_eps))
+            f = self._ffn_infer(params, lp, rmsnorm(h, lp["ln2"], cfg.norm_eps))
             return h + f, nkv
 
         keys = ("k", "v") + (("k_scale", "v_scale") if quantized else ()) \
             + (("k_sub", "v_sub") if int4 else ())
-        xs = (params["layers"], qstate["attn_clip"]) + tuple(pool[k] for k in keys)
+        xs = (self._stack(params), qstate["attn_clip"]) + tuple(pool[k] for k in keys)
         h, nkv = jax.lax.scan(body, h, xs)
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
         idx = jnp.clip(chunk_len - 1, 0, tokens.shape[1] - 1)
@@ -991,15 +1061,12 @@ class Model:
                 seed_first_row=True,
             )
             h = h + a
-            if cfg.moe is not None:
-                f = moe.moe_ffn_infer(lp["moe"], rmsnorm(h, lp["ln2"], cfg.norm_eps), cfg)
-            else:
-                f = gated_mlp(lp["mlp"], rmsnorm(h, lp["ln2"], cfg.norm_eps))
+            f = self._ffn_infer(params, lp, rmsnorm(h, lp["ln2"], cfg.norm_eps))
             return h + f, nkv
 
         keys = ("k", "v") + (("k_scale", "v_scale") if quantized else ()) \
             + (("k_sub", "v_sub") if int4 else ())
-        xs = (params["layers"], qstate["attn_clip"]) + tuple(pool[k] for k in keys)
+        xs = (self._stack(params), qstate["attn_clip"]) + tuple(pool[k] for k in keys)
         h, nkv = jax.lax.scan(body, h, xs)
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
         logits = jnp.einsum("cd,dv->cv", h[0], params["head"].astype(h.dtype))
@@ -1024,13 +1091,10 @@ class Model:
                     sp=cfg.quant.sp_decode,
                 )
                 h = h + a
-                if cfg.moe is not None:
-                    f = moe.moe_ffn_infer(lp["moe"], rmsnorm(h, lp["ln2"], cfg.norm_eps), cfg)
-                else:
-                    f = gated_mlp(lp["mlp"], rmsnorm(h, lp["ln2"], cfg.norm_eps))
+                f = self._ffn_infer(params, lp, rmsnorm(h, lp["ln2"], cfg.norm_eps))
                 return h + f, (nk, nv)
 
-            h, (nk, nv) = jax.lax.scan(body, h, (params["layers"], qstate["attn_clip"], cache["k"], cache["v"]))
+            h, (nk, nv) = jax.lax.scan(body, h, (self._stack(params), qstate["attn_clip"], cache["k"], cache["v"]))
             new_cache["k"], new_cache["v"] = nk, nv
         elif fam == "ssm":
             def body(h, xs):
@@ -1101,6 +1165,11 @@ class Model:
         logits = jnp.einsum("bd,dv->bv", h[:, -1], params["head"].astype(h.dtype))
         logits = self._mask_padded_vocab(logits)
         return logits, new_cache
+
+
+def _layer_at(stack, i):
+    """Layer ``i`` of a stacked parameter tree."""
+    return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), stack)
 
 
 def build_model(cfg) -> Model:

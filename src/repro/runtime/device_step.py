@@ -73,12 +73,14 @@ def decode_scan(step_kv, kv, tokens, lens, active, budget, temperature, top_k,
     sampler. ``step_kv(tokens, kv, lens, active)`` is the engine-specific
     model call (slot-ragged or paged); ``kv`` is the engine's cache pytree —
     {"k","v"} for the slot cache, plus "k_scale"/"v_scale" planes for an
-    int8 paged pool."""
+    int8 paged pool. Where ``step_kv`` returns a third value (a MoE model's
+    per-layer routing counts), the scan stacks it over steps and returns it
+    last."""
     eos = -1 if eos_id is None else eos_id
 
     def step(carry, _):
         kv, tokens, lens, active, budget, key = carry
-        logits, kv = step_kv(tokens, kv, lens, active)
+        logits, kv, *extra = step_kv(tokens, kv, lens, active)
         key, sub = jax.random.split(key)
         if sampler == "greedy":
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -96,13 +98,14 @@ def decode_scan(step_kv, kv, tokens, lens, active, budget, temperature, top_k,
         return (kv, new_tokens, new_lens, new_active, new_budget, key), (
             emitted,
             emit_mask,
+            *extra,
         )
 
     init = (kv, tokens, lens, active, budget, key)
-    (kv, tokens, lens, active, budget, key), (emitted, masks) = jax.lax.scan(
+    (kv, tokens, lens, active, budget, key), (emitted, masks, *extra) = jax.lax.scan(
         step, init, None, length=steps
     )
-    return kv, tokens, lens, active, budget, key, emitted, masks
+    return (kv, tokens, lens, active, budget, key, emitted, masks, *extra)
 
 
 class _DeviceStep:
@@ -313,15 +316,22 @@ class PagedDeviceStep(_DeviceStep):
 
     def _chunk_fn(self, params, pool, tables, tokens, lens, active, budget,
                   temperature, top_k, top_p, key, *, steps, sampler):
+        """The decode chunk, and for a MoE model its routing: (2,) int32,
+        live rows routed to the held experts and the (step, layer, expert)
+        triples with at least one, summed over the chunk (None otherwise)."""
         def step_kv(tokens, pool, lens, active):
             return self.model.decode_step_paged(
                 params, tokens, pool, tables, lens, active, self.qstate,
                 block_size=self.block_size,
             )
 
-        return decode_scan(step_kv, pool, tokens, lens, active, budget,
-                           temperature, top_k, top_p, key, steps=steps,
-                           sampler=sampler, eos_id=self.eos_id, max_seq=self.max_seq)
+        out = decode_scan(step_kv, pool, tokens, lens, active, budget,
+                          temperature, top_k, top_p, key, steps=steps,
+                          sampler=sampler, eos_id=self.eos_id, max_seq=self.max_seq)
+        if self.cfg.moe is None:
+            return out + (None,)
+        counts = out[8]  # (steps, layers, held experts)
+        return out[:8] + (jnp.stack([counts.sum(), (counts > 0).sum()]).astype(jnp.int32),)
 
     # ---------------------------------------------------------------- wrappers
 

@@ -492,6 +492,11 @@ class PagedEngine(EngineCore, Engine):
                 kv_heads=kv // tp if kv % tp == 0 else kv,
                 head_dim=cfg.resolved_head_dim, kv_dtype=cache_dtype,
             )
+        # (layer, held expert) pairs of a MoE decode step, for moe_expert_slots
+        self._moe_slots_per_step = 0
+        if cfg.moe is not None:
+            lo, hi = cfg.held_experts
+            self._moe_slots_per_step = (cfg.num_layers - cfg.moe.first_dense) * (hi - lo)
         self._key = jax.random.PRNGKey(config.seed)
         self._pool = self._dev.init_pool()
         # raw jitted (pool, src, dst) -> pool CoW copy; tests drive it directly
@@ -596,7 +601,7 @@ class PagedEngine(EngineCore, Engine):
                 self._budget, self._temperature, self._top_k, self._top_p, self._key,
                 steps=steps, sampler=self._pick_sampler(),
             )
-        pool, tokens, lens, active, budget, self._key, emitted, masks = out
+        pool, tokens, lens, active, budget, self._key, emitted, masks, routing = out
         with span("engine.device_wait"):
             jax.block_until_ready(emitted)
         self.stats["decode_time"] += time.perf_counter() - t0
@@ -612,6 +617,11 @@ class PagedEngine(EngineCore, Engine):
                     table_tokens=self.blocks_per_table * self.block_size)
                 self.stats["decode_kv_tiles"] += walked
                 self.stats["decode_kv_tiles_full"] += full
+            if routing is not None:
+                rows, touched = (int(n) for n in np.asarray(routing))
+                self.stats["moe_rows"] += rows
+                self.stats["moe_experts_touched"] += touched
+                self.stats["moe_expert_slots"] += len(masks) * self._moe_slots_per_step
         return n_out
 
     # ------------------------------------------------- speculative decoding

@@ -704,7 +704,8 @@ class EngineCore(HostCore):
 
         self.stats.update(prompt_tokens=0, prefix_hit_tokens=0,
                           prefill_tokens=0, prefill_chunks=0, preemptions=0,
-                          decode_kv_tiles=0, decode_kv_tiles_full=0)
+                          decode_kv_tiles=0, decode_kv_tiles_full=0,
+                          moe_rows=0, moe_experts_touched=0, moe_expert_slots=0)
         # CoW device copies planned but not yet performed: (src, dst) pairs in
         # the order they must execute (see class docstring)
         self.pending_copies: list[tuple[int, int]] = []

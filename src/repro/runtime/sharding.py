@@ -95,8 +95,8 @@ def make_activation_rules(cfg, mesh: Mesh) -> dict[str, P]:
         # (sequence-parallel attention; exact, softmax is row-wise)
         rules["qrows"] = P(dp, None, "model", None)
         rules["score_rows"] = P(dp, None, "model", None)
-    if cfg.moe is not None and _div(cfg.moe.num_experts, tp):
-        rules["experts"] = P("model", dp, None, None)  # (E, G, C, D)
+    if cfg.moe is not None and _div(cfg.held_experts[1] - cfg.held_experts[0], tp):
+        rules["experts"] = P("model", dp, None, None)  # (E held, G, C, D)
     if cfg.ssm_state and _div(cfg.ssm_heads, tp):
         rules["ssm_heads"] = P(dp, None, "model", None)  # (B, S, nh, hd)
     return rules

@@ -25,11 +25,16 @@ def per_expert_params(cfg) -> int:
 
 
 def count_active_params(cfg) -> int:
-    """Params touched per token (MoE: only top-k routed experts active)."""
+    """Params touched per token. MoE: of each expert layer's routed experts
+    only the top-k count; the leading dense layers, the shared experts and
+    the router count whole. The routed experts held are taken out and the
+    top-k put back, so a program that holds one chip's share of the experts
+    (``cfg.held_experts``) counts what a token touches across the deployment."""
     n = count_params(cfg)
     if cfg.moe is not None:
-        inactive = (cfg.moe.num_experts - cfg.moe.top_k) * per_expert_params(cfg) * cfg.num_layers
-        n -= inactive
+        lo, hi = cfg.held_experts
+        moe_layers = cfg.num_layers - cfg.moe.first_dense
+        n -= (hi - lo - cfg.moe.top_k) * per_expert_params(cfg) * moe_layers
     return int(n)
 
 

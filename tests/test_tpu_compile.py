@@ -7,7 +7,9 @@ and paged prefill kernels for a v5e that is described, not attached, at
 yi-6b serving widths (32 q-heads, 4 kv-heads, head_dim 128, 16-token blocks,
 a 1024-block pool) for every pool dtype, plus zamba2's head_dim 80, and the
 decode kernel at the yi-6b.chat deployment's shape (16 slots, 256-entry
-tables, a 1400-block pool) for every pool dtype. Nothing
+tables, a 1400-block pool) for every pool dtype, and both kernels at the
+deepseek-moe-16b.chat deployment's (16 q-heads and 16 kv-heads, group 1,
+a 705-block bf16 pool). Nothing
 runs: a pass means the chip's compiler accepts the kernel, and the compiled
 text holds the Mosaic kernel (``tpu_custom_call``).
 
@@ -33,6 +35,8 @@ SLOTS, MAX_BLOCKS = 4, 128  # 2048-token windows
 CHUNK = 32  # EngineConfig's default prefill chunk
 # the yi-6b.chat deployment: 16 slots, max_seq 4096, a 22,384-token pool
 SERVED_SLOTS, SERVED_MAX_BLOCKS, SERVED_N_BLOCKS = 16, 256, 22384 // BS + 1
+# the deepseek-moe-16b.chat deployment: MHA, 16 heads, an 11,264-token pool
+MOE_HEADS, MOE_N_BLOCKS = 16, 11264 // BS + 1
 
 CASES = [("bf16", 128), ("int8", 128), ("int4", 128), ("bf16", 80)]
 CASE_IDS = [f"{kv}-d{dh}" for kv, dh in CASES]
@@ -57,19 +61,20 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _pool_args(kv_dtype: str, dh: int, sharding, n_blocks: int = N_BLOCKS) -> tuple[tuple, dict]:
+def _pool_args(kv_dtype: str, dh: int, sharding, n_blocks: int = N_BLOCKS,
+               kv: int = KV) -> tuple[tuple, dict]:
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     if kv_dtype == "int4":
-        pool = sds((n_blocks, KV, BS, dh // 2), jnp.uint8)
+        pool = sds((n_blocks, kv, BS, dh // 2), jnp.uint8)
     else:
-        pool = sds((n_blocks, KV, BS, dh), {"bf16": jnp.bfloat16, "int8": jnp.int8}[kv_dtype])
+        pool = sds((n_blocks, kv, BS, dh), {"bf16": jnp.bfloat16, "int8": jnp.int8}[kv_dtype])
     planes = {}
     if kv_dtype in ("int8", "int4"):
-        planes["k_scale"] = planes["v_scale"] = sds((n_blocks, KV), jnp.float32)
+        planes["k_scale"] = planes["v_scale"] = sds((n_blocks, kv), jnp.float32)
     if kv_dtype == "int4":
-        planes["k_sub"] = planes["v_sub"] = sds((n_blocks, KV, kv4_num_sub(BS)), jnp.uint8)
+        planes["k_sub"] = planes["v_sub"] = sds((n_blocks, kv, kv4_num_sub(BS)), jnp.uint8)
     return (pool, pool), planes
 
 
@@ -113,3 +118,25 @@ def test_paged_prefill_compiles_for_v5e(one_chip, kv_dtype, dh):
         q, pk, pv, table, start, exaq_params(2.0, 2), dh**-0.5, **planes
     ).compile()
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_paged_kernels_compile_for_v5e_at_moe_served_shape(one_chip, kernel):
+    """deepseek-moe-16b.chat: 16 kv heads of group 1 (256-token decode
+    tiles at this width, against 512 for yi-6b), 16 slots, 256-entry
+    tables."""
+    (pk, pv), planes = _pool_args("bf16", 128, one_chip, MOE_N_BLOCKS, kv=MOE_HEADS)
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if kernel == "decode":
+        q = jax.ShapeDtypeStruct((SERVED_SLOTS, MOE_HEADS, 1, 128), jnp.bfloat16, sharding=one_chip)
+        lowered = exaq_paged_decode_attention.lower(
+            q, pk, pv, sds((SERVED_SLOTS, SERVED_MAX_BLOCKS)), sds((SERVED_SLOTS,)),
+            exaq_params(2.0, 2), 128**-0.5, **planes)
+    else:
+        q = jax.ShapeDtypeStruct((1, MOE_HEADS, CHUNK, 128), jnp.bfloat16, sharding=one_chip)
+        lowered = exaq_paged_prefill_attention.lower(
+            q, pk, pv, sds((SERVED_MAX_BLOCKS,)), sds(()), exaq_params(2.0, 2), 128**-0.5, **planes)
+    _assert_kernel(lowered.compile())
