@@ -61,12 +61,18 @@ so the kernel never shuffles lanes. q/out/acc stay at the unpacked width
 that padded nibbles decode to, and the V-side garbage lands in output
 lanes the final slice drops.
 
-Layouts: q ``(S, H, 1, Dh)``; pool_k/pool_v ``(N, KV, bs, Dh)`` (int4:
-``(N, KV, bs, Dh/2)`` uint8); block_tables ``(S, MB)`` int32; kv_lens
-``(S,)`` int32; optional k_scale/v_scale ``(N, KV)`` fp32 and int4-only
-k_sub/v_sub ``(N, KV, n_sub)`` uint8. Compiled-mode tiling wants ``bs`` a
-multiple of 8 and the pool's last dim lane-padded (both hold for
-production shapes; tests run interpret mode where any shape goes).
+Layouts: q ``(S, H, 1, Dh)``; pool_k/pool_v the whole stacked planes
+``(L, N, KV, bs, Dh)`` (int4: ``(L, N, KV, bs, Dh/2)`` uint8) and
+``layer``, the scalar-prefetched index of the layer to read: the model
+carries the pool through its layer loop and writes each layer's rows in
+place (DESIGN.md §3), so no per-layer slice of the pool is ever cut out to
+feed the kernel; block_tables ``(S, MB)`` int32; kv_lens ``(S,)`` int32;
+optional k_scale/v_scale ``(L, N, KV)`` fp32 and int4-only k_sub/v_sub
+``(L, N, KV, n_sub)`` uint8, of which only ``layer``'s (kilobytes) reach
+SMEM. Compiled-mode tiling wants ``bs`` a multiple of 8 and the pool's last
+dim lane-padded (both hold for production shapes; a narrower pool has its
+one layer sliced out and padded, and tests run interpret mode where any
+shape goes).
 
 Tensor-parallel contract (DESIGN.md §9): under a mesh whose 'model' axis
 divides KV, ``kernels.ops.paged_decode_attention`` wraps this kernel in a
@@ -97,6 +103,33 @@ _LANES = 128
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+def layer_operands(pool_k, pool_v, layer, width: int, planes):
+    """What both paged kernels read of the stacked pool at ``layer``.
+
+    Returns (pool_k, pool_v, layer (1,) int32, flat scale planes). The
+    payload planes pass whole, to be indexed by ``layer`` in the kernel —
+    unless their last dim is narrower than ``width`` lanes: then only this
+    layer is sliced out and padded (one layer's bytes, never the whole
+    pool's) and the index becomes 0. ``planes`` are the quantized pool's
+    (L, N, KV[, n_sub]) scale planes: this layer's are cut out (kilobytes)
+    and flattened for SMEM, which pads a 2-D array's minor dim to 128 lanes
+    and would blow (N, KV=4) planes up 32x past its 1 MiB at a 1024-block
+    pool; the sub codes widen to int32 (SMEM scalars are 32-bit)."""
+    layer = jnp.asarray(layer, jnp.int32)
+    flat = tuple(
+        jax.lax.dynamic_index_in_dim(p, layer, 0, keepdims=False)
+        .astype(jnp.float32 if jnp.issubdtype(p.dtype, jnp.floating) else jnp.int32)
+        .reshape(-1)
+        for p in planes
+    )
+    if pool_k.shape[-1] != width:
+        pad = ((0, 0),) * 4 + ((0, width - pool_k.shape[-1]),)
+        pool_k, pool_v = (jnp.pad(jax.lax.dynamic_index_in_dim(p, layer, 0), pad)
+                          for p in (pool_k, pool_v))
+        layer = jnp.zeros((), jnp.int32)
+    return pool_k, pool_v, layer.reshape(1), flat
 
 
 def exaq_accumulate_stage(s, m, valid, *, levels: int, clip: float, lut: tuple[float, ...]):
@@ -222,6 +255,7 @@ def decode_tile_tokens(*, block_size: int, max_blocks: int, kv_heads: int, head_
 def _paged_decode_kernel(
     tables_ref,
     lens_ref,
+    layer_ref,
     *refs,
     bs: int,
     mb: int,
@@ -240,9 +274,10 @@ def _paged_decode_kernel(
     """One program per slot, over all its kv heads. Two loops over the
     slot's live tiles of ``tb`` pool blocks, bounded by ``kv_lens[slot]``:
     the first reduces the global row max, the second quantizes on it and
-    accumulates. The pools stay in HBM (``memory_space=pl.ANY``); each tile's
-    live blocks are copied from the scalar-prefetched table into one half of
-    a double buffer while the other half is computed on. ``kv_quant`` pools
+    accumulates. The stacked pools stay in HBM (``memory_space=pl.ANY``);
+    each tile's live blocks of layer ``layer_ref[0]`` are copied from the
+    scalar-prefetched table into one half of a double buffer while the other
+    half is computed on. ``kv_quant`` pools
     carry two extra scalar-prefetch refs — the per-(block, kv-head) dequant
     scales (DESIGN.md §6); ``kv_int4`` pools carry two more — the
     (N, KV, n_sub) sub-block scale codes — and their pools hold *packed*
@@ -257,6 +292,7 @@ def _paged_decode_kernel(
     (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, k_sem, v_sem,
      m_ref, l_ref, acc_ref) = refs
     slot = pl.program_id(0)
+    layer = layer_ref[0]
     # scalar index math uses lax ops: jnp's floor division and modulo trace
     # sign fix-ups that make the kernel slow to lower. A finished slot's
     # stale length may pass the table by one token
@@ -277,7 +313,7 @@ def _paged_decode_kernel(
 
         def copy(b):
             blk = tables_ref[slot, i * tb + b]
-            pltpu.make_async_copy(hbm.at[blk], buf.at[sl, b], sem.at[sl]).start()
+            pltpu.make_async_copy(hbm.at[layer, blk], buf.at[sl, b], sem.at[sl]).start()
 
         def group(g, carry):
             for u in range(_COPY_GROUP):
@@ -294,7 +330,7 @@ def _paged_decode_kernel(
 
     def _wait(hbm, buf, sem, i, sl):
         # every copy moved one block's bytes onto this semaphore: wait as many
-        copy = pltpu.make_async_copy(hbm.at[0], buf.at[sl, 0], sem.at[sl])
+        copy = pltpu.make_async_copy(hbm.at[0, 0], buf.at[sl, 0], sem.at[sl])
 
         def one(b, carry):
             copy.wait()
@@ -398,26 +434,28 @@ def exaq_paged_decode_attention(
     params,
     scale: float,
     *,
+    layer=0,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     k_sub: jnp.ndarray | None = None,
     v_sub: jnp.ndarray | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Fused paged-decode EXAQ attention over a block pool.
+    """Fused paged-decode EXAQ attention over layer ``layer`` of a block pool.
 
-    q: (S, H, 1, D); pool_k/pool_v: (N, KV, bs, D); block_tables: (S, MB)
-    int32 block ids (null-block padded); kv_lens: (S,) live tokens per slot.
-    An int8 pool additionally takes k_scale/v_scale (N, KV) fp32 dequant
-    scales (DESIGN.md §6), scalar-prefetched beside the block tables. A
-    packed int4 pool (uint8 payload at last dim D/2, DESIGN.md §10) also
-    takes k_sub/v_sub (N, KV, n_sub) uint8 sub-block scale codes; nibbles
-    unpack in VMEM after each half-width block DMA.
+    q: (S, H, 1, D); pool_k/pool_v: (L, N, KV, bs, D) stacked over layers;
+    layer: scalar int32, the layer to read; block_tables: (S, MB) int32
+    block ids (null-block padded); kv_lens: (S,) live tokens per slot. An
+    int8 pool additionally takes k_scale/v_scale (L, N, KV) fp32 dequant
+    scales (DESIGN.md §6), whose layer is scalar-prefetched beside the
+    block tables. A packed int4 pool (uint8 payload at last dim D/2,
+    DESIGN.md §10) also takes k_sub/v_sub (L, N, KV, n_sub) uint8 sub-block
+    scale codes; nibbles unpack in VMEM after each half-width block DMA.
     Returns (S, H, 1, D) fp32. Global-grid (exact Algo. 2) semantics.
     """
     S, H, one, D = q.shape
     assert one == 1
-    N, KV, bs, _ = pool_k.shape
+    KV, bs = pool_k.shape[2:4]
     MB = block_tables.shape[1]
     group = H // KV
     kv_quant = pool_k.dtype == jnp.int8
@@ -437,10 +475,10 @@ def exaq_paged_decode_attention(
     if block_q != group:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, block_q - group), (0, 0)))
     if kv_int4:
-        if D % 2 or pool_k.shape[3] != D // 2:
+        if D % 2 or pool_k.shape[-1] != D // 2:
             raise ValueError(
                 f"packed int4 pool last dim must be head_dim/2 "
-                f"(got pool {pool_k.shape[3]}, head_dim {D})"
+                f"(got pool {pool_k.shape[-1]}, head_dim {D})"
             )
         n_sub = k_sub.shape[-1]
         sub_bs = bs // n_sub
@@ -451,10 +489,6 @@ def exaq_paged_decode_attention(
         p_pad = _round_up(max(D // 2, _LANES), _LANES)
         kv_width = p_pad
         d_pad = 2 * p_pad
-        if p_pad != D // 2:
-            ppad = ((0, 0), (0, 0), (0, 0), (0, p_pad - D // 2))
-            pool_k = jnp.pad(pool_k, ppad)
-            pool_v = jnp.pad(pool_v, ppad)
         # the kernel unpacks low nibbles (even dims) to lanes [0, p_pad) and
         # high nibbles (odd dims) to [p_pad, 2*p_pad): split q's dims the same way
         qpad = ((0, 0), (0, 0), (0, 0), (0, p_pad - D // 2))
@@ -466,10 +500,10 @@ def exaq_paged_decode_attention(
         if d_pad != D:
             # production head dims are lane-aligned; the pad only fires on the
             # small shapes tests use (interpret mode), never on the serving path
-            pad = ((0, 0), (0, 0), (0, 0), (0, d_pad - D))
-            q = jnp.pad(q, pad)
-            pool_k = jnp.pad(pool_k, pad)
-            pool_v = jnp.pad(pool_v, pad)
+            q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, d_pad - D)))
+    pool_k, pool_v, layer, planes = layer_operands(
+        pool_k, pool_v, layer, kv_width,
+        tuple(p for p in (k_scale, v_scale, k_sub, v_sub) if p is not None))
 
     tb = decode_tile_tokens(block_size=bs, max_blocks=MB, kv_heads=KV, head_dim=D,
                             kv_dtype="int4" if kv_int4 else pool_k.dtype) // bs
@@ -480,18 +514,10 @@ def exaq_paged_decode_attention(
     def _q_index(s, *prefetch):
         return (s, 0, 0, 0)
 
-    # the dequant scales ride the scalar-prefetch channel and the kernel
-    # indexes them by the same prefetched table entry that steered the DMA.
-    # They go in flat: SMEM pads a 2-D array's minor dim to 128 lanes, which
-    # would blow (N, KV=4) planes up 32x past SMEM's 1 MiB at a 1024-block
-    # pool. int4 adds the (N, KV, n_sub) sub codes, widened to int32 (SMEM
-    # scalars are 32-bit)
-    prefetch = (tables, lens)
-    if want_scales:
-        prefetch += (k_scale.astype(jnp.float32).reshape(-1),
-                     v_scale.astype(jnp.float32).reshape(-1))
-    if kv_int4:
-        prefetch += (k_sub.astype(jnp.int32).reshape(-1), v_sub.astype(jnp.int32).reshape(-1))
+    # the layer's dequant scales ride the scalar-prefetch channel, flat, and
+    # the kernel indexes them by the same prefetched table entry that steered
+    # the DMA (int4 adds the sub codes)
+    prefetch = (tables, lens, layer) + planes
     buf = pltpu.VMEM((2, tb, KV, bs, kv_width), pool_k.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),

@@ -56,13 +56,17 @@ dequantized copy ever exists in HBM. q/out/acc live at the unpacked width
 padded nibbles decode to, and V-side garbage lands in output lanes >= Dh
 that the final slice drops.
 
-Layouts: q ``(1, H, C, Dh)``; pool_k/pool_v ``(N, KV, bs, Dh)`` (int4:
-``(N, KV, bs, Dh/2)`` uint8); block_table ``(MB,)`` int32; start scalar
-int32 (tokens already cached); optional k_scale/v_scale ``(N, KV)`` fp32
-and int4-only k_sub/v_sub ``(N, KV, n_sub)`` uint8. Compiled-mode tiling
-wants ``bs`` a multiple of 8 and the pool's last dim lane-padded
-(production shapes satisfy both; tests run interpret mode where any shape
-goes).
+Layouts: q ``(1, H, C, Dh)``; pool_k/pool_v the whole stacked planes
+``(L, N, KV, bs, Dh)`` (int4: ``(L, N, KV, bs, Dh/2)`` uint8) and ``layer``,
+scalar-prefetched: the K/V index maps return ``(layer, block, head, 0, 0)``,
+so the pool the model carries through its layer loop is read in place, with
+no per-layer slice (DESIGN.md §3); block_table ``(MB,)`` int32; start scalar
+int32 (tokens already cached); optional k_scale/v_scale ``(L, N, KV)`` fp32
+and int4-only k_sub/v_sub ``(L, N, KV, n_sub)`` uint8, of which only
+``layer``'s reach SMEM. Compiled-mode tiling wants ``bs`` a multiple of 8
+and the pool's last dim lane-padded (production shapes satisfy both; a
+narrower pool has its one layer sliced out and padded, and tests run
+interpret mode where any shape goes).
 
 Tensor-parallel contract (DESIGN.md §9): under a mesh whose 'model' axis
 divides KV, ``kernels.ops.paged_prefill_attention`` wraps this kernel in a
@@ -92,6 +96,7 @@ from repro.kernels.exaq_paged_attention import (
     _NEG_BIG,
     _round_up,
     exaq_accumulate_stage,
+    layer_operands,
     load_pool_block,
 )
 from repro.kernels.kv_codec import kv4_num_sub
@@ -119,8 +124,8 @@ def _paged_prefill_kernel(
     """Grid (KV, 2*MB): table entries 0..MB-1 are the max pass, MB..2*MB-1
     the quantize+accumulate pass. Scratch (m, l, acc) carries across the
     chunk axis; the BlockSpec index maps (not this body) steer the pool DMA.
-    ``info_ref`` is (2,): [start, start + C] — row positions and the live
-    window length. ``kv_quant`` pools carry two extra scalar-prefetch refs,
+    ``info_ref`` is (3,): [start, start + C, layer] — row positions, the live
+    window length, and the layer of the stacked pool the index maps read. ``kv_quant`` pools carry two extra scalar-prefetch refs,
     the per-(block, kv-head) dequant scales (DESIGN.md §6); ``kv_int4``
     pools carry two more — the (N, KV, n_sub) sub-block scale codes — and
     their K/V refs hold *packed* nibbles at half width (DESIGN.md §10)."""
@@ -200,28 +205,31 @@ def exaq_paged_prefill_attention(
     params,
     scale: float,
     *,
+    layer=0,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     k_sub: jnp.ndarray | None = None,
     v_sub: jnp.ndarray | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Fused chunked-prefill EXAQ attention for one request over a block pool.
+    """Fused chunked-prefill EXAQ attention for one request over layer
+    ``layer`` of a block pool.
 
     q: (1, H, C, D) the chunk's projected queries (rows at global positions
-    ``start + i``); pool_k/pool_v: (N, KV, bs, D) with this chunk's K/V
-    already scattered in; block_table: (MB,) int32 block ids (null-block
-    padded); start: scalar int32 tokens cached before this chunk. An int8
-    pool additionally takes k_scale/v_scale (N, KV) fp32 dequant scales
-    (DESIGN.md §6), scalar-prefetched beside the table. A packed int4 pool
+    ``start + i``); pool_k/pool_v: (L, N, KV, bs, D) stacked over layers,
+    with this chunk's K/V already scattered into ``layer``; layer: scalar
+    int32; block_table: (MB,) int32 block ids (null-block padded); start:
+    scalar int32 tokens cached before this chunk. An int8 pool additionally
+    takes k_scale/v_scale (L, N, KV) fp32 dequant scales (DESIGN.md §6),
+    whose layer is scalar-prefetched beside the table. A packed int4 pool
     (uint8 payload at last dim D/2, DESIGN.md §10) also takes k_sub/v_sub
-    (N, KV, n_sub) uint8 sub-block scale codes; nibbles unpack in VMEM
+    (L, N, KV, n_sub) uint8 sub-block scale codes; nibbles unpack in VMEM
     after each half-width block DMA. Returns
     (1, H, C, D) fp32. Global-grid (exact Algo. 2) semantics — bit-identical
     to a one-shot prefill of the same window.
     """
     _, H, C, D = q.shape
-    N, KV, bs, _ = pool_k.shape
+    KV, bs = pool_k.shape[2:4]
     MB = block_table.shape[0]
     group = H // KV
     kv_quant = pool_k.dtype == jnp.int8
@@ -241,10 +249,10 @@ def exaq_paged_prefill_attention(
     if block_q != group * C:
         q = jnp.pad(q, ((0, 0), (0, block_q - group * C), (0, 0)))
     if kv_int4:
-        if D % 2 or pool_k.shape[3] != D // 2:
+        if D % 2 or pool_k.shape[-1] != D // 2:
             raise ValueError(
                 f"packed int4 pool last dim must be head_dim/2 "
-                f"(got pool {pool_k.shape[3]}, head_dim {D})"
+                f"(got pool {pool_k.shape[-1]}, head_dim {D})"
             )
         n_sub = k_sub.shape[-1]
         sub_bs = bs // n_sub
@@ -254,10 +262,6 @@ def exaq_paged_prefill_attention(
         p_pad = _round_up(max(D // 2, _LANES), _LANES)
         kv_width = p_pad
         d_pad = 2 * p_pad
-        if p_pad != D // 2:
-            ppad = ((0, 0), (0, 0), (0, 0), (0, p_pad - D // 2))
-            pool_k = jnp.pad(pool_k, ppad)
-            pool_v = jnp.pad(pool_v, ppad)
         if d_pad != D:
             q = jnp.pad(q, ((0, 0), (0, 0), (0, d_pad - D)))
     else:
@@ -267,46 +271,40 @@ def exaq_paged_prefill_attention(
         if d_pad != D:
             # production head dims are lane-aligned; the pad only fires on the
             # small shapes tests use (interpret mode), never on the serving path
-            pad = ((0, 0), (0, 0), (0, d_pad - D))
-            q = jnp.pad(q, pad)
-            pad4 = ((0, 0), (0, 0), (0, 0), (0, d_pad - D))
-            pool_k = jnp.pad(pool_k, pad4)
-            pool_v = jnp.pad(pool_v, pad4)
+            q = jnp.pad(q, ((0, 0), (0, 0), (0, d_pad - D)))
+    pool_k, pool_v, layer, planes = layer_operands(
+        pool_k, pool_v, layer, kv_width,
+        tuple(p for p in (k_scale, v_scale, k_sub, v_sub) if p is not None))
 
     table = block_table.astype(jnp.int32)
     start = jnp.asarray(start, jnp.int32)
-    info = jnp.stack([start, start + C])
+    info = jnp.concatenate([jnp.stack([start, start + C]), layer])
     lut = tuple(float(x) for x in params.lut_np())
 
     def _k_index(h, j, tbl, inf, *sc):
         # future/dead-tail entries -> null block; consecutive identical
         # indices are a single DMA, so bytes track start + C, not MB*bs
         t = j % MB
-        return (jnp.where(t * bs < inf[1], tbl[t], 0), h, 0, 0)
+        return (inf[2], jnp.where(t * bs < inf[1], tbl[t], 0), h, 0, 0)
 
     def _v_index(h, j, tbl, inf, *sc):
         # V is only consumed by the accumulate pass; pin the max pass (and
         # future blocks) to the null block so V moves over HBM exactly once
         t = j % MB
-        return (jnp.where((j >= MB) & (t * bs < inf[1]), tbl[t], 0), h, 0, 0)
+        return (inf[2], jnp.where((j >= MB) & (t * bs < inf[1]), tbl[t], 0), h, 0, 0)
 
     def _q_index(h, j, tbl, inf, *sc):
         return (h, 0, 0)
 
-    # scale planes prefetch flat, as in the decode kernel (SMEM lane padding)
-    prefetch = (table, info)
-    if want_scales:
-        prefetch += (k_scale.astype(jnp.float32).reshape(-1),
-                     v_scale.astype(jnp.float32).reshape(-1))
-    if kv_int4:
-        prefetch += (k_sub.astype(jnp.int32).reshape(-1), v_sub.astype(jnp.int32).reshape(-1))
+    # the layer's scale planes prefetch flat, as in the decode kernel
+    prefetch = (table, info) + planes
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(KV, 2 * MB),
         in_specs=[
             pl.BlockSpec((1, block_q, d_pad), _q_index),
-            pl.BlockSpec((1, 1, bs, kv_width), _k_index),
-            pl.BlockSpec((1, 1, bs, kv_width), _v_index),
+            pl.BlockSpec((None, 1, 1, bs, kv_width), _k_index),
+            pl.BlockSpec((None, 1, 1, bs, kv_width), _v_index),
         ],
         out_specs=pl.BlockSpec((1, block_q, d_pad), _q_index),
         scratch_shapes=[

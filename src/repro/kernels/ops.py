@@ -247,11 +247,14 @@ def gather_block_kv(pool_k: jnp.ndarray, pool_v: jnp.ndarray, block_tables: jnp.
                     k_scale: jnp.ndarray | None = None,
                     v_scale: jnp.ndarray | None = None,
                     k_sub: jnp.ndarray | None = None,
-                    v_sub: jnp.ndarray | None = None):
+                    v_sub: jnp.ndarray | None = None,
+                    *, layer=0):
     """Assemble per-slot contiguous KV from a paged block pool (DESIGN.md §3).
 
-    pool_{k,v}: (N, KV, bs, Dh) global block pool; block_tables: (S, MB) int32
-    block ids per slot -> (S, KV, MB*bs, Dh) laid out in block-table order, so
+    pool_{k,v}: (L, N, KV, bs, Dh) global block pool stacked over layers, of
+    which layer ``layer`` is read — indexed ``pool[layer, ids]`` in one
+    gather, so no slice of the whole layer is cut out; block_tables: (S, MB)
+    int32 block ids per slot -> (S, KV, MB*bs, Dh) laid out in block-table order, so
     flat position ``p`` of slot ``s`` is block ``block_tables[s, p // bs]``
     offset ``p % bs`` — the invariant every paged caller masks against via
     ``kv_lens``. Table padding (the null block, id 0) gathers garbage that the
@@ -267,12 +270,12 @@ def gather_block_kv(pool_k: jnp.ndarray, pool_v: jnp.ndarray, block_tables: jnp.
     works under jit with traced lengths). Results are unchanged: dead-tail
     lanes were always masked out by the caller.
 
-    ``k_scale``/``v_scale`` (N, KV) fp32, required for an int8 pool
+    ``k_scale``/``v_scale`` (L, N, KV) fp32, required for an int8 pool
     (DESIGN.md §6): each gathered block is dequantized ``codes * scale``
     before assembly, so callers always see fp values — this is the
     *dequantizing oracle* the fused int8 kernel is tested against. A packed
     int4 pool (uint8 payload, DESIGN.md §10) additionally requires the
-    ``k_sub``/``v_sub`` (N, KV, n_sub) uint8 sub-block scale codes; blocks
+    ``k_sub``/``v_sub`` (L, N, KV, n_sub) uint8 sub-block scale codes; blocks
     are nibble-unpacked and dequantized at
     ``block_scale * sub_code / 15`` per sub-block during assembly — the
     dequantizing oracle of the fused int4 kernels.
@@ -296,18 +299,18 @@ def gather_block_kv(pool_k: jnp.ndarray, pool_v: jnp.ndarray, block_tables: jnp.
         )
     if kv_lens is not None:
         MB = block_tables.shape[1]
-        bs = pool_k.shape[2]
+        bs = pool_k.shape[3]
         kv_lens = jnp.broadcast_to(jnp.asarray(kv_lens, jnp.int32).reshape(-1),
                                    (block_tables.shape[0],))
         live = jnp.arange(MB, dtype=jnp.int32)[None, :] * bs < kv_lens[:, None]
         block_tables = jnp.where(live, block_tables, 0)  # 0 == kv_pool.NULL_BLOCK
 
     def g(pool, scale, sub):
-        b = pool[block_tables]  # (S, MB, KV, bs, Dh) — or (…, bs, Dh//2) packed
+        b = pool[layer, block_tables]  # (S, MB, KV, bs, Dh) — or (…, bs, Dh//2) packed
         if sub is not None:
-            b = kv4_dequantize_block(b, scale[block_tables], sub[block_tables])
+            b = kv4_dequantize_block(b, scale[layer, block_tables], sub[layer, block_tables])
         elif scale is not None:
-            b = b.astype(jnp.float32) * scale[block_tables][..., None, None]
+            b = b.astype(jnp.float32) * scale[layer, block_tables][..., None, None]
         b = jnp.swapaxes(b, 1, 2)  # (S, KV, MB, bs, Dh)
         S, KV, MB, bs, Dh = b.shape
         return b.reshape(S, KV, MB * bs, Dh)
@@ -338,16 +341,16 @@ def _tp_mesh(num_kv_heads: int):
     return mesh
 
 
-def _tp_paged_attention(mesh, local_fn, head_args, table_args, scales):
+def _tp_paged_attention(mesh, local_fn, q, planes, table_args):
     """shard_map a fused paged kernel over the 'model' axis (DESIGN.md §9).
 
-    ``head_args`` (q and the two pool planes) shard their head axis — axis 1
-    on every one of them — so each shard DMAs only its local heads from a
-    local pool partition; ``table_args`` (block tables, lens, start) stay
-    replicated scalar-prefetch inputs; quantized-pool ``scales`` follow the
-    pool's head split on *their* axis 1, whatever their rank — int8's
-    (N, KV) block-scale planes and int4's (N, KV, n_sub) sub-code planes
-    both shard kv-heads. Because q heads and kv heads shard by the same
+    ``q`` shards its head axis 1; ``planes`` — the two stacked pool planes
+    and any quantized-pool scale planes, every one (L, N, KV, ...) — shard
+    their kv-head axis 2, so each shard DMAs only its local heads from a
+    local pool partition, and int8's (L, N, KV) block-scale planes and
+    int4's (L, N, KV, n_sub) sub-code planes hold exactly its heads' scales;
+    ``table_args`` (block tables, lens, start, layer) stay replicated
+    scalar-prefetch inputs. Because q heads and kv heads shard by the same
     factor, a shard's query group h // group lands exactly on its local kv
     heads — the kernels' index maps need no global-head offsets, and each
     (slot, head) row is computed whole on exactly one shard, so the sharded
@@ -358,32 +361,26 @@ def _tp_paged_attention(mesh, local_fn, head_args, table_args, scales):
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    heads = P(None, "model", None, None)  # q / pool planes are all rank 4
+    heads = P(None, "model", None, None)  # q and the output are rank 4
     in_specs = (
-        tuple(heads for _ in head_args)
+        (heads,)
+        + tuple(P(None, None, "model", *(None,) * (jnp.ndim(a) - 3)) for a in planes)
         + tuple(P(*(None,) * jnp.ndim(a)) for a in table_args)
-        + tuple(
-            P(*("model" if i == 1 else None for i in range(jnp.ndim(a))))
-            for a in scales
-        )
     )
     fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=in_specs,
-        out_specs=P(None, "model", None, None),
+        out_specs=heads,
         check_vma=False,
     )
-    out = fn(*head_args, *table_args, *scales)
+    out = fn(q, *planes, *table_args)
     return jax.lax.with_sharding_constraint(out, NamedSharding(mesh, P()))
 
 
-def _pool_scale_args(k_scale, v_scale, k_sub, v_sub):
-    """Pool scale arrays as a positional tuple: () fp, 2 int8, 4 int4."""
-    if k_sub is not None:
-        return (k_scale, v_scale, k_sub, v_sub)
-    if k_scale is not None:
-        return (k_scale, v_scale)
-    return ()
+def _given(**planes) -> dict:
+    """The scale planes that are given, by name: none for an fp pool, two
+    for int8, four for int4."""
+    return {k: p for k, p in planes.items() if p is not None}
 
 
 def paged_decode_attention(
@@ -395,6 +392,7 @@ def paged_decode_attention(
     params: QuantParams,
     scale: float,
     *,
+    layer=0,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     k_sub: jnp.ndarray | None = None,
@@ -402,7 +400,8 @@ def paged_decode_attention(
     block_kv: int = 512,
     use_kernel: bool = True,
 ) -> jnp.ndarray:
-    """Decode attention over a block-paged KV cache with EXAQ softmax.
+    """Decode attention over layer ``layer`` of a block-paged KV cache with
+    EXAQ softmax.
 
     ``use_kernel=True`` (the serving hot path) dispatches the fused Pallas
     kernel (``kernels/exaq_paged_attention.py``): block-table-indexed K/V
@@ -417,38 +416,36 @@ def paged_decode_attention(
     paging composes with the DESIGN.md §2 combine — block boundaries are
     invisible to the softmax, and the two paths agree to fp32 roundoff.
 
-    For an int8 pool (DESIGN.md §6) pass ``k_scale``/``v_scale`` (N, KV):
-    the fused kernel scalar-prefetches them and dequantizes blocks in VMEM;
-    the gather path dequantizes during assembly — either way dequant never
-    round-trips through HBM at fp width. For a packed int4 pool (DESIGN.md
-    §10) additionally pass ``k_sub``/``v_sub`` (N, KV, n_sub): the fused
-    kernel unpacks nibbles in VMEM right after each half-width block DMA.
+    For an int8 pool (DESIGN.md §6) pass ``k_scale``/``v_scale`` (L, N, KV):
+    the fused kernel scalar-prefetches the layer's and dequantizes blocks in
+    VMEM; the gather path dequantizes during assembly — either way dequant
+    never round-trips through HBM at fp width. For a packed int4 pool
+    (DESIGN.md §10) additionally pass ``k_sub``/``v_sub`` (L, N, KV, n_sub):
+    the fused kernel unpacks nibbles in VMEM right after each half-width
+    block DMA.
 
-    q: (S, H, 1, Dh); pool_{k,v}: (N, KV, bs, Dh); block_tables: (S, MB);
-    kv_lens: (S,) live positions per slot -> (S, H, 1, Dh).
+    q: (S, H, 1, Dh); pool_{k,v}: (L, N, KV, bs, Dh); layer: scalar int32;
+    block_tables: (S, MB); kv_lens: (S,) live positions per slot ->
+    (S, H, 1, Dh).
     """
     if use_kernel:
-        mesh = _tp_mesh(pool_k.shape[1])
-        if mesh is not None:
-            def local(q, pk, pv, bt, kl, *scales):
-                ks, vs, ksub, vsub = (tuple(scales) + (None,) * 4)[:4]
-                return exaq_paged_decode_attention(
-                    q, pk, pv, bt, kl, params, scale,
-                    k_scale=ks, v_scale=vs, k_sub=ksub, v_sub=vsub,
-                    interpret=on_cpu()
-                )
+        scales = _given(k_scale=k_scale, v_scale=v_scale, k_sub=k_sub, v_sub=v_sub)
 
-            return _tp_paged_attention(
-                mesh, local, (q, pool_k, pool_v), (block_tables, kv_lens),
-                _pool_scale_args(k_scale, v_scale, k_sub, v_sub),
+        def local(q, pk, pv, *rest):
+            *sc, bt, kl, lyr = rest
+            return exaq_paged_decode_attention(
+                q, pk, pv, bt, kl, params, scale, layer=lyr,
+                **dict(zip(scales, sc)), interpret=on_cpu()
             )
-        return exaq_paged_decode_attention(
-            q, pool_k, pool_v, block_tables, kv_lens, params, scale,
-            k_scale=k_scale, v_scale=v_scale, k_sub=k_sub, v_sub=v_sub,
-            interpret=on_cpu()
-        )
+
+        planes = (pool_k, pool_v, *scales.values())
+        table_args = (block_tables, kv_lens, jnp.asarray(layer, jnp.int32))
+        mesh = _tp_mesh(pool_k.shape[2])
+        if mesh is not None:
+            return _tp_paged_attention(mesh, local, q, planes, table_args)
+        return local(q, *planes, *table_args)
     k, v = gather_block_kv(pool_k, pool_v, block_tables, kv_lens,
-                           k_scale, v_scale, k_sub, v_sub)
+                           k_scale, v_scale, k_sub, v_sub, layer=layer)
     return decode_attention(q, k, v, kv_lens, params, scale, block_kv=block_kv, use_kernel=False)
 
 
@@ -461,13 +458,15 @@ def paged_prefill_attention(
     params: QuantParams,
     scale: float,
     *,
+    layer=0,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     k_sub: jnp.ndarray | None = None,
     v_sub: jnp.ndarray | None = None,
     use_kernel: bool = True,
 ) -> jnp.ndarray:
-    """One chunk of chunked-prefill attention over a block-paged KV cache.
+    """One chunk of chunked-prefill attention over layer ``layer`` of a
+    block-paged KV cache.
 
     The prefill-side mirror of ``paged_decode_attention`` (DESIGN.md §7):
     the chunk's K/V are already scattered into the pool; this attends the
@@ -487,39 +486,35 @@ def paged_prefill_attention(
     each row's quantization grid at its true global max, so chunking is
     invisible to the softmax (§2) and the two paths agree to fp32 roundoff.
 
-    For an int8 pool (DESIGN.md §6) pass ``k_scale``/``v_scale`` (N, KV):
-    the fused kernel scalar-prefetches them and dequantizes blocks in VMEM;
-    the gather path dequantizes during assembly. For a packed int4 pool
-    (DESIGN.md §10) additionally pass ``k_sub``/``v_sub`` (N, KV, n_sub).
+    For an int8 pool (DESIGN.md §6) pass ``k_scale``/``v_scale`` (L, N, KV):
+    the fused kernel scalar-prefetches the layer's and dequantizes blocks in
+    VMEM; the gather path dequantizes during assembly. For a packed int4
+    pool (DESIGN.md §10) additionally pass ``k_sub``/``v_sub``
+    (L, N, KV, n_sub).
 
-    q: (1, H, C, Dh); pool_{k,v}: (N, KV, bs, Dh); block_table: (MB,);
-    start: scalar int32 tokens already cached -> (1, H, C, Dh) fp32.
+    q: (1, H, C, Dh); pool_{k,v}: (L, N, KV, bs, Dh); layer: scalar int32;
+    block_table: (MB,); start: scalar int32 tokens already cached ->
+    (1, H, C, Dh) fp32.
     """
     if use_kernel:
-        mesh = _tp_mesh(pool_k.shape[1])
-        if mesh is not None:
-            start_arr = jnp.asarray(start, jnp.int32)
+        scales = _given(k_scale=k_scale, v_scale=v_scale, k_sub=k_sub, v_sub=v_sub)
 
-            def local(q, pk, pv, bt, st, *scales):
-                ks, vs, ksub, vsub = (tuple(scales) + (None,) * 4)[:4]
-                return exaq_paged_prefill_attention(
-                    q, pk, pv, bt, st, params, scale,
-                    k_scale=ks, v_scale=vs, k_sub=ksub, v_sub=vsub,
-                    interpret=on_cpu()
-                )
-
-            return _tp_paged_attention(
-                mesh, local, (q, pool_k, pool_v), (block_table, start_arr),
-                _pool_scale_args(k_scale, v_scale, k_sub, v_sub),
+        def local(q, pk, pv, *rest):
+            *sc, bt, st, lyr = rest
+            return exaq_paged_prefill_attention(
+                q, pk, pv, bt, st, params, scale, layer=lyr,
+                **dict(zip(scales, sc)), interpret=on_cpu()
             )
-        return exaq_paged_prefill_attention(
-            q, pool_k, pool_v, block_table, start, params, scale,
-            k_scale=k_scale, v_scale=v_scale, k_sub=k_sub, v_sub=v_sub,
-            interpret=on_cpu()
-        )
+
+        planes = (pool_k, pool_v, *scales.values())
+        table_args = (block_table, jnp.asarray(start, jnp.int32), jnp.asarray(layer, jnp.int32))
+        mesh = _tp_mesh(pool_k.shape[2])
+        if mesh is not None:
+            return _tp_paged_attention(mesh, local, q, planes, table_args)
+        return local(q, *planes, *table_args)
     C = q.shape[2]
     kg, vg = gather_block_kv(pool_k, pool_v, block_table[None], start + C,
-                             k_scale, v_scale, k_sub, v_sub)  # (1, KV, W, Dh)
+                             k_scale, v_scale, k_sub, v_sub, layer=layer)  # (1, KV, W, Dh)
     kk, vv = _repeat_kv(q, kg, vg)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, kk).astype(jnp.float32) * scale
     rows = start + jnp.arange(C, dtype=jnp.int32)

@@ -377,7 +377,7 @@ def attention_decode_ragged(params, x, cfg, statics: AttnStatics, clip, cache_k,
 
 
 def attention_decode_paged(params, x, cfg, statics: AttnStatics, clip, pool_k, pool_v,
-                           block_tables, lens, active, k_scale=None, v_scale=None,
+                           layer, block_tables, lens, active, k_scale=None, v_scale=None,
                            k_sub=None, v_sub=None):
     """Slot-batched one-token decode over a *block-paged* KV cache (DESIGN.md §3).
 
@@ -389,13 +389,18 @@ def attention_decode_paged(params, x, cfg, statics: AttnStatics, clip, pool_k, p
     slots scatter to the reserved null block (id 0) so a freed slot can never
     corrupt blocks that were recycled to another request.
 
+    The pool planes are the whole stacked (L, ...) pool that the model's layer
+    loop carries (DESIGN.md §3): the rows land at ``[layer, blk, :, off]``,
+    so XLA updates the donated buffer in place, and the read paths index
+    ``layer`` — no per-layer slice of the pool is cut out or written back.
+
     For an int8 pool (DESIGN.md §6) the scatter *quantizes*: the new token's
     per-kv-head codes land at the block's scale — seeding it
     (``ops.kv_write_scales``) when this is the block's first write — and the
     read paths dequantize, so fp values never reach HBM. ``k_scale``/
-    ``v_scale`` are the per-layer (N, KV) scale planes; None means an fp pool.
+    ``v_scale`` are the (L, N, KV) scale planes; None means an fp pool.
     A packed int4 pool (DESIGN.md §10) additionally carries the
-    ``k_sub``/``v_sub`` (N, KV, n_sub) sub-block scale-code planes: the token
+    ``k_sub``/``v_sub`` (L, N, KV, n_sub) sub-block scale-code planes: the token
     seeds its target sub-block's code (immutable once set, like the block
     scale) and its row lands as packed nibbles at the effective scale
     ``block_scale * sub_code / 15``. Head-dim-adjacent packing keeps the
@@ -415,15 +420,16 @@ def attention_decode_paged(params, x, cfg, statics: AttnStatics, clip, pool_k, p
     clip as a compile-time constant, so a *calibrated* per-layer qstate is
     honored by the gather path only.
 
-    x: (S, 1, D); pool_{k,v}: (N, KV, bs, Dh); block_tables: (S, MB) int32;
-    lens: (S,) int32; active: (S,) bool; k_scale/v_scale: (N, KV) fp32 or
-    None; k_sub/v_sub: (N, KV, n_sub) uint8 or None.
+    x: (S, 1, D); pool_{k,v}: (L, N, KV, bs, Dh); layer: scalar int32;
+    block_tables: (S, MB) int32; lens: (S,) int32; active: (S,) bool;
+    k_scale/v_scale: (L, N, KV) fp32 or None; k_sub/v_sub: (L, N, KV, n_sub)
+    uint8 or None.
     Returns (out (S, 1, D), new_kv) where new_kv is (pool_k, pool_v) for fp
     pools, (pool_k, pool_v, k_scale, v_scale) for int8 pools, and
     (pool_k, pool_v, k_scale, v_scale, k_sub, v_sub) for int4 pools.
     """
     B = x.shape[0]
-    bs = pool_k.shape[2]
+    bs = pool_k.shape[3]
     quantized = k_scale is not None
     int4 = k_sub is not None
     positions = lens.astype(jnp.int32)[:, None]  # (S, 1) per-slot rope position
@@ -436,40 +442,41 @@ def attention_decode_paged(params, x, cfg, statics: AttnStatics, clip, pool_k, p
         # §6's immutable-scale scatter at the int4 range (DESIGN.md §10): the
         # block scale seeds at margin*amax/7 iff unset, the token's sub-block
         # code seeds iff unset, and the row quantizes at the effective scale
-        # block * code / 15 into packed nibbles. Advanced index [blk, :, sub]
-        # selects each slot's one touched sub-block as an (S, KV) plane.
+        # block * code / 15 into packed nibbles. Advanced index
+        # [layer, blk, :, sub] selects each slot's one touched sub-block as
+        # an (S, KV) plane.
         sub_bs = bs // k_sub.shape[-1]
         sub = off // sub_bs  # (S,) the one sub-block this token lands in
         amax_k = jnp.max(jnp.abs(kn), axis=-1)  # (S, KV)
         amax_v = jnp.max(jnp.abs(vn), axis=-1)
-        ks_new = ops.kv4_write_block_scales(amax_k, k_scale[blk])
-        vs_new = ops.kv4_write_block_scales(amax_v, v_scale[blk])
+        ks_new = ops.kv4_write_block_scales(amax_k, k_scale[layer, blk])
+        vs_new = ops.kv4_write_block_scales(amax_v, v_scale[layer, blk])
         kc_new = ops.kv4_write_sub_scales(amax_k[..., None], ks_new,
-                                          k_sub[blk, :, sub][..., None])[..., 0]  # (S, KV)
+                                          k_sub[layer, blk, :, sub][..., None])[..., 0]  # (S, KV)
         vc_new = ops.kv4_write_sub_scales(amax_v[..., None], vs_new,
-                                          v_sub[blk, :, sub][..., None])[..., 0]
+                                          v_sub[layer, blk, :, sub][..., None])[..., 0]
         se_k = ops.kv4_effective_scale(ks_new, kc_new[..., None])[..., 0]
         se_v = ops.kv4_effective_scale(vs_new, vc_new[..., None])[..., 0]
-        new_pool_k = pool_k.at[blk, :, off].set(ops.kv4_quantize(kn, se_k))
-        new_pool_v = pool_v.at[blk, :, off].set(ops.kv4_quantize(vn, se_v))
-        k_scale = k_scale.at[blk].set(ks_new)
-        v_scale = v_scale.at[blk].set(vs_new)
-        k_sub = k_sub.at[blk, :, sub].set(kc_new)
-        v_sub = v_sub.at[blk, :, sub].set(vc_new)
+        new_pool_k = _write_rows(pool_k, layer, blk, off, ops.kv4_quantize(kn, se_k))
+        new_pool_v = _write_rows(pool_v, layer, blk, off, ops.kv4_quantize(vn, se_v))
+        k_scale = k_scale.at[layer, blk].set(ks_new)
+        v_scale = v_scale.at[layer, blk].set(vs_new)
+        k_sub = _write_rows(k_sub, layer, blk, sub, kc_new)
+        v_sub = _write_rows(v_sub, layer, blk, sub, vc_new)
     elif quantized:
         # per-slot per-kv-head amax seeds the target block's scale iff unset;
         # a set scale is immutable (saturating append) so published prefix
         # bytes never change (DESIGN.md §6). Inactive slots land on the null
         # block, whose scale/payload are garbage sinks, never read unmasked.
-        ks_new = ops.kv_write_scales(jnp.max(jnp.abs(kn), axis=-1), k_scale[blk])  # (S, KV)
-        vs_new = ops.kv_write_scales(jnp.max(jnp.abs(vn), axis=-1), v_scale[blk])
-        new_pool_k = pool_k.at[blk, :, off].set(ops.kv_quantize(kn, ks_new[..., None]))
-        new_pool_v = pool_v.at[blk, :, off].set(ops.kv_quantize(vn, vs_new[..., None]))
-        k_scale = k_scale.at[blk].set(ks_new)
-        v_scale = v_scale.at[blk].set(vs_new)
+        ks_new = ops.kv_write_scales(jnp.max(jnp.abs(kn), axis=-1), k_scale[layer, blk])  # (S, KV)
+        vs_new = ops.kv_write_scales(jnp.max(jnp.abs(vn), axis=-1), v_scale[layer, blk])
+        new_pool_k = _write_rows(pool_k, layer, blk, off, ops.kv_quantize(kn, ks_new[..., None]))
+        new_pool_v = _write_rows(pool_v, layer, blk, off, ops.kv_quantize(vn, vs_new[..., None]))
+        k_scale = k_scale.at[layer, blk].set(ks_new)
+        v_scale = v_scale.at[layer, blk].set(vs_new)
     else:
-        new_pool_k = pool_k.at[blk, :, off].set(kn.astype(pool_k.dtype))
-        new_pool_v = pool_v.at[blk, :, off].set(vn.astype(pool_v.dtype))
+        new_pool_k = _write_rows(pool_k, layer, blk, off, kn)
+        new_pool_v = _write_rows(pool_v, layer, blk, off, vn)
     qh = jnp.swapaxes(q, 1, 2)  # (S, H, 1, Dh)
     kv_lens = lens.astype(jnp.int32) + 1
     dh = cfg.resolved_head_dim
@@ -482,11 +489,12 @@ def attention_decode_paged(params, x, cfg, statics: AttnStatics, clip, pool_k, p
 
         p = exaq_params(cfg.quant.sigma_default, statics.bits, rule=cfg.quant.clip_rule)
         o = ops.paged_decode_attention(qh, new_pool_k, new_pool_v, block_tables, kv_lens,
-                                       p, dh**-0.5, k_scale=k_scale, v_scale=v_scale,
-                                       k_sub=k_sub, v_sub=v_sub)
+                                       p, dh**-0.5, layer=layer, k_scale=k_scale,
+                                       v_scale=v_scale, k_sub=k_sub, v_sub=v_sub)
     else:
         kg, vg = ops.gather_block_kv(new_pool_k, new_pool_v, block_tables, kv_lens,
-                                     k_scale, v_scale, k_sub, v_sub)  # (S, KV, W, Dh)
+                                     k_scale, v_scale, k_sub, v_sub,
+                                     layer=layer)  # (S, KV, W, Dh)
         group = cfg.num_heads // cfg.num_kv_heads
         kk = _repeat_kv(kg, group)
         vv = _repeat_kv(vg, group)
@@ -503,8 +511,8 @@ def attention_decode_paged(params, x, cfg, statics: AttnStatics, clip, pool_k, p
 
 
 def attention_prefill_chunk(params, x, cfg, statics: AttnStatics, clip, pool_k, pool_v,
-                            block_table, start, blk_t, off_t, k_scale=None, v_scale=None,
-                            k_sub=None, v_sub=None, seed_first_row=False):
+                            layer, block_table, start, blk_t, off_t, k_scale=None,
+                            v_scale=None, k_sub=None, v_sub=None, seed_first_row=False):
     """One chunk of chunked prefill against a paged cache (DESIGN.md §3).
 
     Processes ``C`` prompt tokens at global positions ``start + i`` for one
@@ -515,7 +523,11 @@ def attention_prefill_chunk(params, x, cfg, statics: AttnStatics, clip, pool_k, 
     now includes this chunk's keys. Because the EXAQ grid anchors at each
     row's global max, chunking the prefill leaves the softmax bit-identical
     to a one-shot prefill of the same prompt (§2: partial histograms add
-    exactly).
+    exactly). As in ``attention_decode_paged``, the pool planes are the
+    whole stacked (L, ...) pool the layer loop carries: rows land at
+    ``[layer, blk_t, :, off_t]`` in place and the read paths index
+    ``layer``; only the quantized pool's small per-layer scale planes are
+    cut out, updated and written back whole.
 
     Attention dispatch (DESIGN.md §7, fused paged prefill): with
     ``use_fused_kernel`` + exaq the fused Pallas kernel
@@ -549,10 +561,11 @@ def attention_prefill_chunk(params, x, cfg, statics: AttnStatics, clip, pool_k, 
     never saw them — scales stay immutable once set, and the rejected rows'
     payload codes sit past ``kv_lens``, where no read path looks.
 
-    x: (1, C, D) chunk embeddings (right-padded); block_table: (MB,) int32;
+    x: (1, C, D) chunk embeddings (right-padded); pool_{k,v}:
+    (L, N, KV, bs, Dh); layer: scalar int32; block_table: (MB,) int32;
     start: scalar int32 (tokens already cached); blk_t/off_t: (C,) int32;
-    k_scale/v_scale: (N, KV) fp32 or None; k_sub/v_sub: (N, KV, n_sub)
-    uint8 or None.
+    k_scale/v_scale: (L, N, KV) fp32 or None; k_sub/v_sub:
+    (L, N, KV, n_sub) uint8 or None.
     Returns (out (1, C, D), new_kv) where new_kv is (pool_k, pool_v) for fp
     pools, (pool_k, pool_v, k_scale, v_scale) for int8 pools, and
     (pool_k, pool_v, k_scale, v_scale, k_sub, v_sub) for int4 pools.
@@ -562,8 +575,12 @@ def attention_prefill_chunk(params, x, cfg, statics: AttnStatics, clip, pool_k, 
     int4 = k_sub is not None
     positions = (start + jnp.arange(C, dtype=jnp.int32))[None, :]  # (1, C)
     q, k, v = _project_qkv(params, x, cfg, positions, rope=True)
+    # the quantized pool's scale planes of this layer, (N, KV[, n_sub]):
+    # seeded below, written back whole before the read paths run
+    lks, lvs, lksub, lvsub = (None if p is None else p[layer]
+                              for p in (k_scale, v_scale, k_sub, v_sub))
     if int4:
-        bs_pool = pool_k.shape[2]
+        bs_pool = pool_k.shape[3]
         sub_bs = bs_pool // k_sub.shape[-1]
         sub_t = off_t // sub_bs  # (C,) each row's target sub-block
         tok_amax_k = jnp.max(jnp.abs(k[0]), axis=-1)  # (C, KV)
@@ -580,22 +597,25 @@ def attention_prefill_chunk(params, x, cfg, statics: AttnStatics, clip, pool_k, 
             blk_amax_v = jnp.where(first_blk, tok_amax_v, 0.0)
             sub_amax_k = jnp.where(first_sub, tok_amax_k, 0.0)
             sub_amax_v = jnp.where(first_sub, tok_amax_v, 0.0)
-        amax_k = jnp.zeros_like(k_scale).at[blk_t].max(blk_amax_k)
-        amax_v = jnp.zeros_like(v_scale).at[blk_t].max(blk_amax_v)
-        k_scale = ops.kv4_write_block_scales(amax_k, k_scale)
-        v_scale = ops.kv4_write_block_scales(amax_v, v_scale)
-        amax_sub_k = jnp.zeros(k_sub.shape, jnp.float32).at[blk_t, :, sub_t].max(sub_amax_k)
-        amax_sub_v = jnp.zeros(v_sub.shape, jnp.float32).at[blk_t, :, sub_t].max(sub_amax_v)
-        k_sub = ops.kv4_write_sub_scales(amax_sub_k, k_scale, k_sub)
-        v_sub = ops.kv4_write_sub_scales(amax_sub_v, v_scale, v_sub)
-        se_k = ops.kv4_effective_scale(k_scale, k_sub)[blk_t, :, sub_t]  # (C, KV)
-        se_v = ops.kv4_effective_scale(v_scale, v_sub)[blk_t, :, sub_t]
-        new_pool_k = pool_k.at[blk_t, :, off_t].set(ops.kv4_quantize(k[0], se_k))
-        new_pool_v = pool_v.at[blk_t, :, off_t].set(ops.kv4_quantize(v[0], se_v))
+        amax_k = jnp.zeros_like(lks).at[blk_t].max(blk_amax_k)
+        amax_v = jnp.zeros_like(lvs).at[blk_t].max(blk_amax_v)
+        lks = ops.kv4_write_block_scales(amax_k, lks)
+        lvs = ops.kv4_write_block_scales(amax_v, lvs)
+        amax_sub_k = jnp.zeros(lksub.shape, jnp.float32).at[blk_t, :, sub_t].max(sub_amax_k)
+        amax_sub_v = jnp.zeros(lvsub.shape, jnp.float32).at[blk_t, :, sub_t].max(sub_amax_v)
+        lksub = ops.kv4_write_sub_scales(amax_sub_k, lks, lksub)
+        lvsub = ops.kv4_write_sub_scales(amax_sub_v, lvs, lvsub)
+        se_k = ops.kv4_effective_scale(lks, lksub)[blk_t, :, sub_t]  # (C, KV)
+        se_v = ops.kv4_effective_scale(lvs, lvsub)[blk_t, :, sub_t]
+        new_pool_k = _write_rows(pool_k, layer, blk_t, off_t, ops.kv4_quantize(k[0], se_k))
+        new_pool_v = _write_rows(pool_v, layer, blk_t, off_t, ops.kv4_quantize(v[0], se_v))
+        k_sub = _with_layer(k_sub, layer, lksub)
+        v_sub = _with_layer(v_sub, layer, lvsub)
     elif quantized:
         # group the chunk's rows by target block: scatter-max their per-head
-        # amax onto the (N, KV) scale plane, seed unset scales, then quantize
-        # each row at its block's scale. Padded rows target the null block.
+        # amax onto the layer's (N, KV) scale plane, seed unset scales, then
+        # quantize each row at its block's scale. Padded rows target the null
+        # block.
         tok_amax_k = jnp.max(jnp.abs(k[0]), axis=-1)  # (C, KV)
         tok_amax_v = jnp.max(jnp.abs(v[0]), axis=-1)
         if seed_first_row:
@@ -603,15 +623,20 @@ def attention_prefill_chunk(params, x, cfg, statics: AttnStatics, clip, pool_k, 
             first_blk = ((jnp.arange(C, dtype=jnp.int32) == 0) | (off_t == 0))[:, None]
             tok_amax_k = jnp.where(first_blk, tok_amax_k, 0.0)
             tok_amax_v = jnp.where(first_blk, tok_amax_v, 0.0)
-        amax_k = jnp.zeros_like(k_scale).at[blk_t].max(tok_amax_k)
-        amax_v = jnp.zeros_like(v_scale).at[blk_t].max(tok_amax_v)
-        k_scale = ops.kv_write_scales(amax_k, k_scale)
-        v_scale = ops.kv_write_scales(amax_v, v_scale)
-        new_pool_k = pool_k.at[blk_t, :, off_t].set(ops.kv_quantize(k[0], k_scale[blk_t][..., None]))
-        new_pool_v = pool_v.at[blk_t, :, off_t].set(ops.kv_quantize(v[0], v_scale[blk_t][..., None]))
+        amax_k = jnp.zeros_like(lks).at[blk_t].max(tok_amax_k)
+        amax_v = jnp.zeros_like(lvs).at[blk_t].max(tok_amax_v)
+        lks = ops.kv_write_scales(amax_k, lks)
+        lvs = ops.kv_write_scales(amax_v, lvs)
+        new_pool_k = _write_rows(pool_k, layer, blk_t, off_t,
+                                 ops.kv_quantize(k[0], lks[blk_t][..., None]))
+        new_pool_v = _write_rows(pool_v, layer, blk_t, off_t,
+                                 ops.kv_quantize(v[0], lvs[blk_t][..., None]))
     else:
-        new_pool_k = pool_k.at[blk_t, :, off_t].set(k[0].astype(pool_k.dtype))  # (C, KV, Dh) targets
-        new_pool_v = pool_v.at[blk_t, :, off_t].set(v[0].astype(pool_v.dtype))
+        new_pool_k = _write_rows(pool_k, layer, blk_t, off_t, k[0])  # (C, KV, Dh) rows
+        new_pool_v = _write_rows(pool_v, layer, blk_t, off_t, v[0])
+    if quantized:
+        k_scale = _with_layer(k_scale, layer, lks)
+        v_scale = _with_layer(v_scale, layer, lvs)
 
     qh = jnp.swapaxes(q, 1, 2)  # (1, H, C, Dh)
     dh = cfg.resolved_head_dim
@@ -623,14 +648,14 @@ def attention_prefill_chunk(params, x, cfg, statics: AttnStatics, clip, pool_k, 
 
         p = exaq_params(cfg.quant.sigma_default, statics.bits, rule=cfg.quant.clip_rule)
         o = ops.paged_prefill_attention(qh, new_pool_k, new_pool_v, block_table, start,
-                                        p, dh**-0.5, k_scale=k_scale, v_scale=v_scale,
-                                        k_sub=k_sub, v_sub=v_sub)
+                                        p, dh**-0.5, layer=layer, k_scale=k_scale,
+                                        v_scale=v_scale, k_sub=k_sub, v_sub=v_sub)
     else:
         # window live length: everything cached before this chunk plus the
         # chunk itself — entries past ceil((start+C)/bs) clamp to null
         kg, vg = ops.gather_block_kv(new_pool_k, new_pool_v, block_table[None],
                                      start + C, k_scale, v_scale,
-                                     k_sub, v_sub)  # (1, KV, W, Dh)
+                                     k_sub, v_sub, layer=layer)  # (1, KV, W, Dh)
         group = cfg.num_heads // cfg.num_kv_heads
         kk = _repeat_kv(kg, group)
         vv = _repeat_kv(vg, group)
@@ -645,6 +670,32 @@ def attention_prefill_chunk(params, x, cfg, statics: AttnStatics, clip, pool_k, 
               + ((k_scale, v_scale) if quantized else ())
               + ((k_sub, v_sub) if int4 else ()))
     return out, new_kv
+
+
+def _write_rows(plane, layer, blk, off, rows):
+    """A stacked pool plane (L, N, KV, n, ...) with row r of ``rows``
+    (R, KV, ...) written at ``[layer, blk[r], :, off[r]]``.
+
+    The write reads the R target blocks whole, sets in each every row that
+    lands in it (the last, where two land on one place — which only the
+    null block's garbage sink sees), and writes the blocks back whole, so
+    targets named twice get the same bytes. A write of whole blocks keeps
+    the carried pool in the layout the fused kernels read and updates it in
+    place; a scatter of single rows makes XLA lay the whole pool out anew
+    for every layer's kernel call (DESIGN.md §3)."""
+    old = plane[layer, blk]  # (R, KV, n, ...)
+    n = plane.shape[3]
+    lands = (blk[:, None, None] == blk[None, :, None]) \
+        & (off[None, :, None] == jnp.arange(n, dtype=off.dtype)[None, None, :])  # (R, R, n)
+    src = jnp.max(jnp.where(lands, jnp.arange(blk.shape[0])[None, :, None], -1), axis=1)
+    new = jnp.moveaxis(rows.astype(plane.dtype)[jnp.maximum(src, 0)], 1, 2)  # (R, KV, n, ...)
+    keep = (src < 0).reshape(src.shape[:1] + (1,) + src.shape[1:] + (1,) * (old.ndim - 3))
+    return plane.at[layer, blk].set(jnp.where(keep, old, new))
+
+
+def _with_layer(plane, layer, value):
+    """A stacked (L, ...) pool plane with layer ``layer`` set to ``value``."""
+    return jax.lax.dynamic_update_index_in_dim(plane, value, layer, 0)
 
 
 def sp_decode_attention(qh, k_new, v_new, cache_k, cache_v, pos, cfg, statics: AttnStatics, clip):

@@ -764,35 +764,27 @@ class Model:
         assert cfg.family in ("dense", "vlm", "moe"), (
             f"paged decode requires an attention KV cache, got family={cfg.family!r}"
         )
-        int4 = pool["k"].dtype == jnp.uint8
-        quantized = int4 or pool["k"].dtype == jnp.int8
-
         routed = cfg.moe is not None
 
-        def body(h, xs):
-            lp, clip, pk, pv, *sc = xs
-            a, nkv = attn.attention_decode_paged(
+        def run_layer(lp, clip, i, h, pk, pv, *sc):
+            a, planes = attn.attention_decode_paged(
                 lp["attn"], rmsnorm(h, lp["ln1"], cfg.norm_eps), cfg, statics, clip,
-                pk, pv, block_tables, lens, active, *sc,
+                pk, pv, i, block_tables, lens, active, *sc,
             )
             h = h + a
             x = rmsnorm(h, lp["ln2"], cfg.norm_eps)
             if not routed:
-                return h + self._ffn_infer(params, lp, x), nkv
+                return h + self._ffn_infer(params, lp, x), planes, None
             f, counts = self._ffn_infer(params, lp, x, rows=active[:, None])
-            return h + f, (nkv, counts)
+            return h + f, planes, counts
 
-        keys = ("k", "v") + (("k_scale", "v_scale") if quantized else ()) \
-            + (("k_sub", "v_sub") if int4 else ())
-        xs = (self._stack(params), qstate["attn_clip"]) + tuple(pool[k] for k in keys)
-        h, ys = jax.lax.scan(body, h, xs)
+        h, new_pool, counts = self._pool_layer_loop(params, qstate, h, pool, run_layer)
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
         logits = jnp.einsum("bd,dv->bv", h[:, -1], params["head"].astype(h.dtype))
         logits = self._mask_padded_vocab(logits)
         if not routed:
-            return logits, dict(zip(keys, ys))
-        nkv, counts = ys
-        return logits, dict(zip(keys, nkv)), counts
+            return logits, new_pool
+        return logits, new_pool, counts
 
     def _decode_step_paged_state(self, params, h, pool, block_tables, lens, active,
                                  qstate, statics, block_size):
@@ -859,13 +851,13 @@ class Model:
 
                 hh, (nconv, nssm) = jax.lax.scan(inner, hh, (gp, gconv, gssm))
                 cat = jnp.concatenate([hh, h0], axis=-1)
-                a, nkv = attn.attention_decode_paged(
+                a, (nk, nv) = attn.attention_decode_paged(
                     params["shared"]["attn"], rmsnorm(cat, params["shared"]["ln1"], cfg.norm_eps),
-                    cfg, statics, clip, pk, pv, block_tables, lens, active,
+                    cfg, statics, clip, pk[None], pv[None], 0, block_tables, lens, active,
                 )
                 hh = hh + a
                 f = gated_mlp(params["shared"]["mlp"], rmsnorm(hh, params["shared"]["ln2"], cfg.norm_eps))
-                return hh + f, (nconv, nssm) + tuple(nkv)
+                return hh + f, (nconv, nssm, nk[0], nv[0])
 
             h, (nconv, nssm, nk, nv) = jax.lax.scan(
                 group, h,
@@ -917,29 +909,55 @@ class Model:
         assert cfg.family in ("dense", "vlm", "moe"), (
             f"paged prefill requires an attention KV cache, got family={cfg.family!r}"
         )
-        int4 = pool["k"].dtype == jnp.uint8
-        quantized = int4 or pool["k"].dtype == jnp.int8
-
-        def body(h, xs):
-            lp, clip, pk, pv, *sc = xs
-            a, nkv = attn.attention_prefill_chunk(
-                lp["attn"], rmsnorm(h, lp["ln1"], cfg.norm_eps), cfg, statics, clip,
-                pk, pv, block_table, start, blk_t, off_t, *sc,
-            )
-            h = h + a
-            f = self._ffn_infer(params, lp, rmsnorm(h, lp["ln2"], cfg.norm_eps))
-            return h + f, nkv
-
-        keys = ("k", "v") + (("k_scale", "v_scale") if quantized else ()) \
-            + (("k_sub", "v_sub") if int4 else ())
-        xs = (self._stack(params), qstate["attn_clip"]) + tuple(pool[k] for k in keys)
-        h, nkv = jax.lax.scan(body, h, xs)
+        h, new_pool = self._prefill_layer_loop(params, qstate, h, pool, block_table, start,
+                                               blk_t, off_t, statics)
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
         idx = jnp.clip(chunk_len - 1, 0, tokens.shape[1] - 1)
         h_last = jax.lax.dynamic_index_in_dim(h[0], idx, axis=0, keepdims=False)
         logits = jnp.einsum("d,dv->v", h_last, params["head"].astype(h.dtype))[None]
         logits = self._mask_padded_vocab(logits)
-        return logits, dict(zip(keys, nkv))
+        return logits, new_pool
+
+    def _pool_layer_loop(self, params, qstate, h, pool, run_layer):
+        """The attention families' layer loop over a paged KV pool
+        (DESIGN.md §3): a scan whose carry holds ``h`` and the whole stacked
+        (L, ...) pool planes ("k", "v" and any scale planes), with each
+        layer's weights, clip and index as its inputs. ``run_layer(lp, clip, i,
+        h, *planes)`` -> (h, planes, y) writes layer ``i``'s rows into the
+        planes in place and reads them back by index, so the pool is neither
+        sliced per layer nor rebuilt as a stacked scan output. Returns
+        (h, new pool, the ys stacked over layers)."""
+        keys = tuple(k for k in ("k", "v", "k_scale", "v_scale", "k_sub", "v_sub") if k in pool)
+
+        def body(carry, xs):
+            h, planes = carry
+            h, planes, y = run_layer(*xs, h, *planes)
+            return (h, tuple(planes)), y
+
+        xs = (self._stack(params), qstate["attn_clip"],
+              jnp.arange(self.cfg.num_layers, dtype=jnp.int32))
+        (h, planes), ys = jax.lax.scan(body, (h, tuple(pool[k] for k in keys)), xs)
+        return h, dict(zip(keys, planes)), ys
+
+    def _prefill_layer_loop(self, params, qstate, h, pool, block_table, start, blk_t, off_t,
+                            statics, seed_first_row=False):
+        """``_pool_layer_loop`` for a chunk of one request's prompt rows, the
+        body of both ``prefill_paged_chunk`` and ``verify_paged_chunk``:
+        -> (h, new pool)."""
+        cfg = self.cfg
+
+        def run_layer(lp, clip, i, h, pk, pv, *sc):
+            a, planes = attn.attention_prefill_chunk(
+                lp["attn"], rmsnorm(h, lp["ln1"], cfg.norm_eps), cfg, statics, clip,
+                pk, pv, i, block_table, start, blk_t, off_t, *sc,
+                seed_first_row=seed_first_row,
+            )
+            h = h + a
+            f = self._ffn_infer(params, lp, rmsnorm(h, lp["ln2"], cfg.norm_eps))
+            return h + f, planes, None
+
+        h, new_pool, _ = self._pool_layer_loop(params, qstate, h, pool, run_layer)
+        return h, new_pool
 
     def _prefill_paged_chunk_state(self, params, h, pool, block_table, start, chunk_len,
                                    blk_t, off_t, qstate, statics, block_size):
@@ -999,13 +1017,13 @@ class Model:
 
                 hh, (nconv, nssm) = jax.lax.scan(inner, hh, (gp, gconv, gssm))
                 cat = jnp.concatenate([hh, h0_tok], axis=-1)
-                a, nkv = attn.attention_prefill_chunk(
+                a, (nk, nv) = attn.attention_prefill_chunk(
                     params["shared"]["attn"], rmsnorm(cat, params["shared"]["ln1"], cfg.norm_eps),
-                    cfg, statics, clip, pk, pv, block_table, start, blk_t, off_t,
+                    cfg, statics, clip, pk[None], pv[None], 0, block_table, start, blk_t, off_t,
                 )
                 hh = hh + a
                 f = gated_mlp(params["shared"]["mlp"], rmsnorm(hh, params["shared"]["ln2"], cfg.norm_eps))
-                return shard_activation(hh + f, "btd"), (nconv, nssm) + tuple(nkv)
+                return shard_activation(hh + f, "btd"), (nconv, nssm, nk[0], nv[0])
 
             h, (nconv, nssm, nk, nv) = jax.lax.scan(
                 group, h,
@@ -1049,29 +1067,13 @@ class Model:
         )
         qstate = qstate or default_qstate(cfg)
         statics = _statics(cfg)
-        int4 = pool["k"].dtype == jnp.uint8
-        quantized = int4 or pool["k"].dtype == jnp.int8
         h = jnp.take(params["embed"]["tokens"], tokens, axis=0)
-
-        def body(h, xs):
-            lp, clip, pk, pv, *sc = xs
-            a, nkv = attn.attention_prefill_chunk(
-                lp["attn"], rmsnorm(h, lp["ln1"], cfg.norm_eps), cfg, statics, clip,
-                pk, pv, block_table, start, blk_t, off_t, *sc,
-                seed_first_row=True,
-            )
-            h = h + a
-            f = self._ffn_infer(params, lp, rmsnorm(h, lp["ln2"], cfg.norm_eps))
-            return h + f, nkv
-
-        keys = ("k", "v") + (("k_scale", "v_scale") if quantized else ()) \
-            + (("k_sub", "v_sub") if int4 else ())
-        xs = (self._stack(params), qstate["attn_clip"]) + tuple(pool[k] for k in keys)
-        h, nkv = jax.lax.scan(body, h, xs)
+        h, new_pool = self._prefill_layer_loop(params, qstate, h, pool, block_table, start,
+                                               blk_t, off_t, statics, seed_first_row=True)
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
         logits = jnp.einsum("cd,dv->cv", h[0], params["head"].astype(h.dtype))
         logits = self._mask_padded_vocab(logits)
-        return logits, dict(zip(keys, nkv))
+        return logits, new_pool
 
     def decode_step(self, params, tokens, cache, qstate=None):
         """tokens: (B, 1) -> (logits (B, V), new cache)."""

@@ -80,10 +80,10 @@ def quantize_pool():
     from repro.kernels.ops import KV_QMAX, KV_SCALE_MARGIN, kv_quantize
 
     def _quantize(pk, pv):
-        def q(pool):
-            amax = jnp.max(jnp.abs(pool), axis=(2, 3))  # (N, KV)
+        def q(pool):  # (..., N, KV, bs, D) fp -> codes + (..., N, KV) scales
+            amax = jnp.max(jnp.abs(pool), axis=(-2, -1))
             scale = KV_SCALE_MARGIN * amax / KV_QMAX
-            return kv_quantize(pool, scale[:, :, None, None]), scale
+            return kv_quantize(pool, scale[..., None, None]), scale
 
         qk, ks = q(pk.astype(jnp.float32))
         qv, vs = q(pv.astype(jnp.float32))
@@ -112,21 +112,22 @@ def quantize_pool_int4():
     )
 
     def _quantize(pk, pv):
-        def q(pool):  # (N, KV, bs, D) fp -> packed + scales
-            N, KV, bs, D = pool.shape
+        def q(pool):  # (..., N, KV, bs, D) fp -> packed + scales
+            *lead, N, KV, bs, D = pool.shape
             sub_bs = kv4_sub_block(bs)
             n_sub = kv4_num_sub(bs)
             pool = pool.astype(jnp.float32)
-            amax = jnp.max(jnp.abs(pool), axis=(2, 3))  # (N, KV)
+            amax = jnp.max(jnp.abs(pool), axis=(-2, -1))  # (..., N, KV)
             scale = kv4_write_block_scales(amax, jnp.zeros_like(amax))
             amax_sub = jnp.max(
-                jnp.abs(pool.reshape(N, KV, n_sub, sub_bs, D)), axis=(3, 4)
-            )  # (N, KV, n_sub)
+                jnp.abs(pool.reshape(*lead, N, KV, n_sub, sub_bs, D)), axis=(-2, -1)
+            )  # (..., N, KV, n_sub)
             codes = kv4_write_sub_scales(amax_sub, scale, jnp.zeros(amax_sub.shape, jnp.uint8))
             per_tok = jnp.repeat(kv4_effective_scale(scale, codes), sub_bs, axis=-1)
             packed = kv4_quantize(pool, per_tok)
             # block 0 is the reserved null sink: unset grid, zero payload
-            return packed.at[0].set(0), scale.at[0].set(0.0), codes.at[0].set(0)
+            return (packed.at[..., 0, :, :, :].set(0), scale.at[..., 0, :].set(0.0),
+                    codes.at[..., 0, :, :].set(0))
 
         qk, ks, ksub = q(pk)
         qv, vs, vsub = q(pv)

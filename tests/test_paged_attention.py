@@ -19,13 +19,14 @@ from repro.kernels.exaq_paged_attention import decode_tile_tokens, paged_decode_
 RNG = np.random.default_rng(42)
 
 
-def _pool_setup(S, KV, bs, MB, D, *, dtype=jnp.float32, seed=0):
-    """Random pool + disjoint per-slot tables (ids permuted so table order
-    differs from pool order — a bug that ignores the table shows up)."""
+def _pool_setup(S, KV, bs, MB, D, *, dtype=jnp.float32, seed=0, layers=1):
+    """Random pool stacked over ``layers`` (L, N, KV, bs, D) + disjoint
+    per-slot tables (ids permuted so table order differs from pool order —
+    a bug that ignores the table shows up)."""
     rng = np.random.default_rng(seed)
     N = 1 + S * MB
-    pk = jnp.asarray(rng.normal(0, 1, (N, KV, bs, D)), dtype)
-    pv = jnp.asarray(rng.normal(0, 1, (N, KV, bs, D)), dtype)
+    pk = jnp.asarray(rng.normal(0, 1, (layers, N, KV, bs, D)), dtype)
+    pv = jnp.asarray(rng.normal(0, 1, (layers, N, KV, bs, D)), dtype)
     ids = rng.permutation(np.arange(1, N))[: S * MB].reshape(S, MB)
     return pk, pv, jnp.asarray(ids, jnp.int32)
 
@@ -357,10 +358,10 @@ def test_gather_block_kv_clamps_dead_tail_to_null_block():
     # dead tail reads block 0 (the null block), not the table's padding ids
     tail = np.asarray(kg[0, :, 2 * bs :]).reshape(KV, MB - 2, bs, D)
     for b in range(MB - 2):
-        np.testing.assert_array_equal(tail[:, b], np.asarray(pk[0]))
+        np.testing.assert_array_equal(tail[:, b], np.asarray(pk[0, 0]))
     tail1 = np.asarray(vg[1]).reshape(KV, MB, bs, D)
     for b in range(MB):
-        np.testing.assert_array_equal(tail1[:, b], np.asarray(pv[0]))
+        np.testing.assert_array_equal(tail1[:, b], np.asarray(pv[0, 0]))
 
 
 def test_repeat_kv_shared_implementation():
@@ -434,6 +435,170 @@ def test_bytes_model_int4_block_bytes_and_reductions():
     # the gather path's dense intermediate is dequantized fp32 for int4 too
     assert m4["gather_then_read_bytes"] == (
         m4["live_blocks"] * m4["block_bytes"] + 2 * S * MB * KVH * bs * D * 4) * 2
+
+
+# ------------------------------------------------- the pool the layer loop carries
+
+LAYER_HEAD_DIMS = {"bf16": 128, "int8": 128, "int4": 256}  # lane-aligned pool widths
+
+
+def _quantized(kv_dtype, pk, pv, quantize_pool, quantize_pool_int4):
+    """(pool_k, pool_v, scale planes by name) of an fp pool at ``kv_dtype``."""
+    if kv_dtype == "bf16":
+        return pk.astype(jnp.bfloat16), pv.astype(jnp.bfloat16), {}
+    if kv_dtype == "int8":
+        qk, qv, ks, vs = quantize_pool(pk, pv)
+        return qk, qv, {"k_scale": ks, "v_scale": vs}
+    qk, qv, *scales = quantize_pool_int4(pk, pv)
+    return qk, qv, dict(zip(("k_scale", "v_scale", "k_sub", "v_sub"), scales))
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "int4"])
+def test_layer_indexed_kernels_match_sliced_layer(kernel, kv_dtype, quantize_pool,
+                                                  quantize_pool_int4):
+    """Both fused kernels and their gather references read layer ``layer``
+    of the whole stacked pool exactly as they read that layer sliced out
+    alone: bit-identical outputs, at lane-aligned widths, where the kernels
+    index the stacked pool in place instead of slicing it."""
+    S, KV, bs, MB, L, layer = 3, 2, 8, 4, 3, 1
+    D = LAYER_HEAD_DIMS[kv_dtype]
+    p = exaq_params(1.5, 2)
+    pk, pv, tbl = _pool_setup(S, KV, bs, MB, D, seed=40, layers=L)
+    pk, pv, planes = _quantized(kv_dtype, pk, pv, quantize_pool, quantize_pool_int4)
+    sliced = {k: a[layer][None] for k, a in planes.items()}
+    if kernel == "decode":
+        q = jnp.asarray(RNG.normal(0, 1, (S, 2 * KV, 1, D)), jnp.float32)
+        lens = jnp.asarray([5, 17, MB * bs], jnp.int32)
+
+        def run(k, v, lyr, use_kernel, scales):
+            return ops.paged_decode_attention(q, k, v, tbl, lens, p, D**-0.5, layer=lyr,
+                                              use_kernel=use_kernel, **scales)
+    else:
+        q = jnp.asarray(RNG.normal(0, 1, (1, 2 * KV, 6, D)), jnp.float32)
+
+        def run(k, v, lyr, use_kernel, scales):
+            return ops.paged_prefill_attention(q, k, v, tbl[0], jnp.int32(11), p, D**-0.5,
+                                               layer=lyr, use_kernel=use_kernel, **scales)
+    for use_kernel in (True, False):
+        got = run(pk, pv, jnp.int32(layer), use_kernel, planes)
+        want = run(pk[layer][None], pv[layer][None], jnp.int32(0), use_kernel, sliced)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # another layer's bytes are another answer: the index is honoured
+        other = run(pk, pv, jnp.int32(0), use_kernel, planes)
+        assert not np.array_equal(np.asarray(other), np.asarray(got))
+
+
+def _sliced_layer_loop(self, params, qstate, h, pool, run_layer):
+    """The per-layer reference of ``Model._pool_layer_loop``: every layer's
+    pool planes cut out as scan inputs, handed to the layer as a one-layer
+    pool, and stacked back as scan outputs."""
+    keys = tuple(k for k in ("k", "v", "k_scale", "v_scale", "k_sub", "v_sub") if k in pool)
+
+    def body(h, xs):
+        lp, clip, *planes = xs
+        h, planes, y = run_layer(lp, clip, jnp.int32(0), h, *(a[None] for a in planes))
+        return h, (tuple(a[0] for a in planes), y)
+
+    xs = (self._stack(params), qstate["attn_clip"], *(pool[k] for k in keys))
+    h, (planes, ys) = jax.lax.scan(body, h, xs)
+    return h, dict(zip(keys, planes)), ys
+
+
+def _random_pool(model, kv_dtype, n_blocks, bs, seed):
+    """A pool whose every byte is drawn, so that a write lands visibly and
+    an untouched byte can be told from a rewritten one."""
+    rng = np.random.default_rng(seed)
+    pool = model.init_block_pool(n_blocks, bs, {"bf16": jnp.bfloat16, "int8": jnp.int8,
+                                                 "int4": "int4"}[kv_dtype])
+    out = {}
+    for k, a in pool.items():
+        if k.endswith("_scale"):
+            out[k] = jnp.asarray(rng.uniform(0.01, 0.1, a.shape), a.dtype)
+        elif k.endswith("_sub") or a.dtype in (jnp.uint8, jnp.int8):
+            lo, hi = (1, 16) if k.endswith("_sub") else (-100, 100) if a.dtype == jnp.int8 else (0, 256)
+            out[k] = jnp.asarray(rng.integers(lo, hi, a.shape), a.dtype)
+        else:
+            out[k] = jnp.asarray(rng.normal(0, 1, a.shape), a.dtype)
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "verify"])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "int4"])
+@pytest.mark.parametrize("arch", ["yi-6b", "deepseek-moe-16b"])
+def test_carried_pool_matches_per_layer_reference(arch, kv_dtype, program, monkeypatch):
+    """A decode step, a prefill chunk and a verify chunk over the pool their
+    layer loop carries leave every layer's blocks, and return logits,
+    bit-identical to the per-layer reference that slices each layer out and
+    stacks it back — on the fused kernels and on the gather path — and
+    leave every row they do not write untouched, in every layer."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.models.model import Model
+
+    bs, MB, S = 8, 4, 3
+    cfg = get_config(arch).reduced(num_layers=3, head_dim=LAYER_HEAD_DIMS[kv_dtype])
+    N = 1 + S * MB
+    rng = np.random.default_rng(50)
+    tables = jnp.asarray(rng.permutation(np.arange(1, N)).reshape(S, MB), jnp.int32)
+    if program == "decode":
+        lens = jnp.asarray([5, 17, 9], jnp.int32)
+        active = jnp.asarray([True, True, False])
+        rows = [(int(tables[s, lens[s] // bs]) if active[s] else 0, int(lens[s] % bs))
+                for s in range(S)]
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (S, 1)), jnp.int32)
+
+        def call(m, params, pool):
+            out = m.decode_step_paged(params, tokens, pool, tables, lens, active,
+                                      block_size=bs)
+            return out[:2]
+    else:
+        start, C = 11, 8
+        live = C if program == "verify" else 6  # prefill pads its last two rows
+        rows = [(int(tables[0, (start + i) // bs]) if i < live else 0, (start + i) % bs)
+                for i in range(C)]
+        blk_t = jnp.asarray([b for b, _ in rows], jnp.int32)
+        off_t = jnp.asarray([o for _, o in rows], jnp.int32)
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, C)), jnp.int32)
+
+        def call(m, params, pool):
+            if program == "verify":
+                return m.verify_paged_chunk(params, tokens, pool, tables[0], jnp.int32(start),
+                                            blk_t, off_t)
+            return m.prefill_paged_chunk(params, tokens, pool, tables[0], jnp.int32(start),
+                                         jnp.int32(live), blk_t, off_t, block_size=bs)
+
+    written = np.zeros((N, bs), bool)
+    for b, o in rows:
+        written[b, o] = True
+    for fused in (True, False):
+        m = build_model(cfg.with_quant(softmax_impl="exaq", bits=2, use_fused_kernel=fused))
+        params = m.init(jax.random.PRNGKey(1), jnp.float32)
+        pool = _random_pool(m, kv_dtype, N, bs, seed=51)
+        got_logits, got = jax.jit(lambda p, q: call(m, p, q))(params, pool)
+        with monkeypatch.context() as mp:
+            mp.setattr(Model, "_pool_layer_loop", _sliced_layer_loop)
+            want_logits, want = jax.jit(lambda p, q: call(m, p, q))(params, pool)
+        np.testing.assert_array_equal(np.asarray(got_logits), np.asarray(want_logits))
+        assert got.keys() == want.keys() == pool.keys()
+        for k in pool:
+            new, old = np.asarray(got[k]), np.asarray(pool[k])
+            np.testing.assert_array_equal(new, np.asarray(want[k]))
+            # outside the rows written (and the null block, the sink of
+            # padded and inactive rows) every layer's bytes are as they were
+            if k in ("k", "v"):
+                keep = ~written[:, None, :]  # (N, 1, bs) over (N, KV, bs, W)
+                keep = np.broadcast_to(keep[None, ..., None], new.shape).copy()
+            else:
+                hit = np.zeros((N,), bool)
+                hit[[b for b, _ in rows]] = True
+                keep = np.broadcast_to((~hit).reshape((1, N) + (1,) * (new.ndim - 2)),
+                                       new.shape).copy()
+            keep[:, 0] = False
+            np.testing.assert_array_equal(new[keep], old[keep])
+            if k in ("k", "v"):  # and every live row written is new
+                changed = (new != old).any(axis=(0, 2, 4))  # (N, bs)
+                assert all(changed[b, o] for b, o in rows if b)
 
 
 # ------------------------------------------------------- engine greedy parity

@@ -18,13 +18,14 @@ from repro.kernels.exaq_paged_prefill import paged_prefill_bytes_model
 RNG = np.random.default_rng(7)
 
 
-def _window_setup(KV, bs, MB, D, *, dtype=jnp.float32, seed=0):
-    """Random pool + one request's table (ids permuted so table order differs
-    from pool order — a bug that ignores the table shows up)."""
+def _window_setup(KV, bs, MB, D, *, dtype=jnp.float32, seed=0, layers=1):
+    """Random pool stacked over ``layers`` (L, N, KV, bs, D) + one request's
+    table (ids permuted so table order differs from pool order — a bug that
+    ignores the table shows up)."""
     rng = np.random.default_rng(seed)
     N = 1 + 2 * MB
-    pk = jnp.asarray(rng.normal(0, 1, (N, KV, bs, D)), dtype)
-    pv = jnp.asarray(rng.normal(0, 1, (N, KV, bs, D)), dtype)
+    pk = jnp.asarray(rng.normal(0, 1, (layers, N, KV, bs, D)), dtype)
+    pv = jnp.asarray(rng.normal(0, 1, (layers, N, KV, bs, D)), dtype)
     tbl = jnp.asarray(rng.permutation(np.arange(1, N))[:MB], jnp.int32)
     return pk, pv, tbl
 
@@ -158,12 +159,14 @@ def test_int8_fresh_block_scale_seeding_through_chunk_scatter():
     N = 1 + MB
     KV, dh = cfg.num_kv_heads, cfg.resolved_head_dim
     x = jnp.asarray(RNG.normal(0, 0.1, (1, C, cfg.d_model)), jnp.float32)
-    pool_k = jnp.zeros((N, KV, bs, dh), jnp.int8)
+    # a two-layer pool, written at layer 1
+    L, layer = 2, 1
+    pool_k = jnp.zeros((L, N, KV, bs, dh), jnp.int8)
     pool_v = jnp.zeros_like(pool_k)
     # block 1 was written by an earlier chunk (its scale is set and immutable);
     # blocks 2-3 are fresh allocations whose scales must seed from this chunk
-    k_scale = jnp.zeros((N, KV), jnp.float32).at[1].set(0.05)
-    v_scale = jnp.zeros((N, KV), jnp.float32).at[1].set(0.07)
+    k_scale = jnp.zeros((L, N, KV), jnp.float32).at[:, 1].set(0.05)
+    v_scale = jnp.zeros((L, N, KV), jnp.float32).at[:, 1].set(0.07)
     tbl = jnp.asarray([1, 2, 3, 0], jnp.int32)
     blk_t = jnp.asarray([tbl[(start + i) // bs] for i in range(C)], jnp.int32)
     off_t = jnp.asarray([(start + i) % bs for i in range(C)], jnp.int32)
@@ -173,13 +176,16 @@ def test_int8_fresh_block_scale_seeding_through_chunk_scatter():
     for fused in (False, True):
         statics = AttnStatics("exaq", 2, fused)
         o, new_kv = attn.attention_prefill_chunk(
-            params, x, cfg, statics, clip, pool_k, pool_v, tbl,
+            params, x, cfg, statics, clip, pool_k, pool_v, jnp.int32(layer), tbl,
             jnp.int32(start), blk_t, off_t, k_scale, v_scale)
         outs[fused], pools[fused] = o, new_kv
     # scatter is shared: codes and seeded scale planes are identical
     for a, b in zip(pools[False], pools[True]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    ks_new = pools[True][2]
+    # the other layer's planes are untouched
+    for a, b in zip(pools[True], (pool_k, pool_v, k_scale, v_scale)):
+        np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(b[0]))
+    ks_new = pools[True][2][layer]
     assert float(ks_new[1, 0]) == pytest.approx(0.05)  # set scale immutable
     assert float(jnp.min(ks_new[2])) > 0.0             # fresh block seeded
     np.testing.assert_allclose(np.asarray(outs[True]), np.asarray(outs[False]), atol=1e-5)
@@ -244,14 +250,16 @@ def test_int4_fresh_block_seeding_through_chunk_scatter():
     KV, dh = cfg.num_kv_heads, cfg.resolved_head_dim
     n_sub = kv4_num_sub(bs)
     x = jnp.asarray(RNG.normal(0, 0.1, (1, C, cfg.d_model)), jnp.float32)
-    pool_k = jnp.zeros((N, KV, bs, dh // 2), jnp.uint8)
+    # a two-layer pool, written at layer 1
+    L, layer = 2, 1
+    pool_k = jnp.zeros((L, N, KV, bs, dh // 2), jnp.uint8)
     pool_v = jnp.zeros_like(pool_k)
     # block 1 was written by an earlier chunk (scale + sub codes set and
     # immutable); blocks 2-3 are fresh — their whole grid seeds from this chunk
-    k_scale = jnp.zeros((N, KV), jnp.float32).at[1].set(0.05)
-    v_scale = jnp.zeros((N, KV), jnp.float32).at[1].set(0.07)
-    k_sub = jnp.zeros((N, KV, n_sub), jnp.uint8).at[1].set(9)
-    v_sub = jnp.zeros((N, KV, n_sub), jnp.uint8).at[1].set(11)
+    k_scale = jnp.zeros((L, N, KV), jnp.float32).at[:, 1].set(0.05)
+    v_scale = jnp.zeros((L, N, KV), jnp.float32).at[:, 1].set(0.07)
+    k_sub = jnp.zeros((L, N, KV, n_sub), jnp.uint8).at[:, 1].set(9)
+    v_sub = jnp.zeros((L, N, KV, n_sub), jnp.uint8).at[:, 1].set(11)
     tbl = jnp.asarray([1, 2, 3, 0], jnp.int32)
     blk_t = jnp.asarray([tbl[(start + i) // bs] for i in range(C)], jnp.int32)
     off_t = jnp.asarray([(start + i) % bs for i in range(C)], jnp.int32)
@@ -261,13 +269,16 @@ def test_int4_fresh_block_seeding_through_chunk_scatter():
     for fused in (False, True):
         statics = AttnStatics("exaq", 2, fused)
         o, new_kv = attn.attention_prefill_chunk(
-            params, x, cfg, statics, clip, pool_k, pool_v, tbl,
+            params, x, cfg, statics, clip, pool_k, pool_v, jnp.int32(layer), tbl,
             jnp.int32(start), blk_t, off_t, k_scale, v_scale, k_sub, v_sub)
         outs[fused], pools[fused] = o, new_kv
     # scatter is shared: nibbles, scale planes and sub codes are identical
     for a, b in zip(pools[False], pools[True]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    _, _, ks_new, _, ksub_new, vsub_new = pools[True]
+    # the other layer's planes are untouched
+    for a, b in zip(pools[True], (pool_k, pool_v, k_scale, v_scale, k_sub, v_sub)):
+        np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(b[0]))
+    _, _, ks_new, _, ksub_new, vsub_new = (a[layer] for a in pools[True])
     assert float(ks_new[1, 0]) == pytest.approx(0.05)        # scale immutable
     assert int(jnp.min(ksub_new[1])) == 9                    # sub codes immutable
     assert int(jnp.min(vsub_new[1])) == 11

@@ -9,16 +9,25 @@ a 1024-block pool) for every pool dtype, plus zamba2's head_dim 80, and the
 decode kernel at the yi-6b.chat deployment's shape (16 slots, 256-entry
 tables, a 1400-block pool) for every pool dtype, and both kernels at the
 deepseek-moe-16b.chat deployment's (16 q-heads and 16 kv-heads, group 1,
-a 705-block bf16 pool). Nothing
-runs: a pass means the chip's compiler accepts the kernel, and the compiled
-text holds the Mosaic kernel (``tpu_custom_call``).
+a 705-block bf16 pool). The kernels read layer 1 of a two-layer stacked
+pool, as the model's layer loop hands them the whole pool and a layer
+index. Nothing runs: a pass means the chip's compiler accepts the kernel,
+and the compiled text holds the Mosaic kernel (``tpu_custom_call``).
+
+The whole decode-chunk and prefill-chunk programs are compiled too, at
+yi-6b's and deepseek-moe-16b's widths with two layers and each cell's pool:
+the pool rides the layer loop's carry and is written in place, so neither
+program holds a temporary as large as one layer's pool, nor a copy of the
+whole pool (DESIGN.md §3).
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may hold the TPU library, and pytest-xdist workers each
 import every test file.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +40,7 @@ from repro.kernels.exaq_paged_prefill import exaq_paged_prefill_attention
 from repro.kernels.kv_codec import kv4_num_sub
 
 H, KV, BS, N_BLOCKS = 32, 4, 16, 1024
+LAYERS = 2  # the kernels read layer 1 of a two-layer pool
 SLOTS, MAX_BLOCKS = 4, 128  # 2048-token windows
 CHUNK = 32  # EngineConfig's default prefill chunk
 # the yi-6b.chat deployment: 16 slots, max_seq 4096, a 22,384-token pool
@@ -67,14 +77,16 @@ def _pool_args(kv_dtype: str, dh: int, sharding, n_blocks: int = N_BLOCKS,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     if kv_dtype == "int4":
-        pool = sds((n_blocks, kv, BS, dh // 2), jnp.uint8)
+        pool = sds((LAYERS, n_blocks, kv, BS, dh // 2), jnp.uint8)
     else:
-        pool = sds((n_blocks, kv, BS, dh), {"bf16": jnp.bfloat16, "int8": jnp.int8}[kv_dtype])
-    planes = {}
+        pool = sds((LAYERS, n_blocks, kv, BS, dh),
+                   {"bf16": jnp.bfloat16, "int8": jnp.int8}[kv_dtype])
+    planes = {"layer": sds((), jnp.int32)}
     if kv_dtype in ("int8", "int4"):
-        planes["k_scale"] = planes["v_scale"] = sds((n_blocks, kv), jnp.float32)
+        planes["k_scale"] = planes["v_scale"] = sds((LAYERS, n_blocks, kv), jnp.float32)
     if kv_dtype == "int4":
-        planes["k_sub"] = planes["v_sub"] = sds((n_blocks, kv, kv4_num_sub(BS)), jnp.uint8)
+        planes["k_sub"] = planes["v_sub"] = sds((LAYERS, n_blocks, kv, kv4_num_sub(BS)),
+                                                jnp.uint8)
     return (pool, pool), planes
 
 
@@ -140,3 +152,63 @@ def test_paged_kernels_compile_for_v5e_at_moe_served_shape(one_chip, kernel):
         lowered = exaq_paged_prefill_attention.lower(
             q, pk, pv, sds((SERVED_MAX_BLOCKS,)), sds(()), exaq_params(2.0, 2), 128**-0.5, **planes)
     _assert_kernel(lowered.compile())
+
+
+# ------------------------------------------------ whole programs, pool in place
+
+PROGRAM_CELLS = {
+    # config, its served pool, and the registry fields the cell's program sets
+    "yi-6b": (SERVED_N_BLOCKS, {}),
+    "deepseek-moe-16b": (MOE_N_BLOCKS, {"expert_parallel": 4, "expert_shard": 0}),
+}
+
+
+def _programs(sharding, arch):
+    """(decode chunk, prefill chunk) of ``PagedDeviceStep`` compiled for the
+    described chip at ``arch``'s widths with two layers and the cell's pool,
+    bf16 weights and pool, the fused kernels; and the pool's shape."""
+    from repro.configs import get_config
+    from repro.models import init_params
+    from repro.runtime.device_step import PagedDeviceStep
+
+    n_blocks, program = PROGRAM_CELLS[arch]
+    cfg = dataclasses.replace(get_config(arch), num_layers=LAYERS, **program)
+    cfg = cfg.with_quant(softmax_impl="exaq", bits=2, use_fused_kernel=True)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    step = PagedDeviceStep(cfg, params, num_blocks=n_blocks, block_size=BS, max_seq=4096)
+    pool = jax.tree.map(sds, jax.eval_shape(
+        lambda: step.model.init_block_pool(n_blocks, BS, jnp.bfloat16)))
+    i32, S, MB = jnp.int32, SERVED_SLOTS, SERVED_MAX_BLOCKS
+    decode = step._jit_chunk.lower(
+        params, pool, arg(i32, S, MB), arg(i32, S, 1), arg(i32, S), arg(jnp.bool_, S),
+        arg(i32, S), arg(jnp.float32, S), arg(i32, S), arg(jnp.float32, S),
+        arg(jnp.uint32, 2), steps=8, sampler="greedy").compile()
+    prefill = step._jit_prefill_chunk.lower(
+        params, pool, arg(i32, 1, CHUNK), arg(i32, MB), arg(i32), arg(i32),
+        arg(i32, CHUNK), arg(i32, CHUNK)).compile()
+    return decode, prefill, pool["k"].shape
+
+
+@pytest.mark.parametrize("arch", list(PROGRAM_CELLS))
+def test_paged_programs_update_the_pool_in_place_for_v5e(one_chip, arch, monkeypatch):
+    """Neither program copies the pool: no temporary reaches one layer's K
+    and V bytes, and the optimised HLO holds no copy of a whole pool plane."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_cpu", lambda: False)  # lower the kernels for the chip
+    decode, prefill, shape = _programs(one_chip, arch)
+    layer_bytes = 2 * (shape[1] * shape[2] * shape[3] * shape[4]) * 2  # K and V, bf16
+    whole = re.compile(r"= bf16\[%s\]\S* copy(-start)?\(" % ",".join(map(str, shape)))
+    for program in (decode, prefill):
+        _assert_kernel(program)
+        assert program.memory_analysis().temp_size_in_bytes < layer_bytes
+        copies = [line for line in program.as_text().splitlines() if whole.search(line)]
+        assert not copies, copies[:2]
